@@ -59,9 +59,9 @@ def run():
     b = jnp.asarray(np.asarray(sig_k)[rng.randint(0, D, 512)])
     for name, fn in [
         ("sigjaccard_pallas", lambda: jax.block_until_ready(
-            ops.pair_estimate(a, b))),
+            ops.pair_counts(a, b))),
         ("sigjaccard_ref", lambda: jax.block_until_ready(
-            ref.pair_estimate(a, b))),
+            ref.pair_counts(a, b))),
     ]:
         emit(name, timeit(fn), "P=512")
 

@@ -2,7 +2,7 @@
 
 Pallas mistakes in this repo fail late (Mosaic compile error on real
 TPUs, or silent garbage from a mis-indexed block) because CI runs the
-kernels in interpret mode.  Four properties ARE statically checkable
+kernels in interpret mode.  Five properties ARE statically checkable
 at every ``pl.pallas_call`` site, and this rule checks them:
 
 * **index-map arity** — every BlockSpec's ``lambda`` must take exactly
@@ -15,6 +15,14 @@ at every ``pl.pallas_call`` site, and this rule checks them:
   guarantees the padded operand dim divides, DESIGN.md §8) — a raw
   parameter or hardcoded literal tile (other than 1) can stop dividing
   the operand the moment a caller passes a new shape;
+* **rank-1 blocks** — the TPU lowering accepts a rank-1 block only if
+  it is the whole vector or a multiple of 128 lanes, and XLA lays a
+  32-bit vector of 1024+ elements out in 1024-element tiles that the
+  block must match.  A rank-1 block that moves along a grid axis is not
+  the whole vector, so its tile must resolve to a multiple of 1024 (a
+  clamp ``min(t, X)`` with such a ``t`` yields either ``t`` or the
+  whole vector); otherwise carry the vector as a 2-D ``(n, 1)`` /
+  ``(1, n)`` block;
 * **VMEM budget** — a static upper-bound estimate per kernel: all
   resolvable block tiles + ``scratch_shapes`` + (for rank-3 grids) the
   broadcast cube over the distinct tile symbols, the dominant term of
@@ -206,6 +214,21 @@ class PallasSpec(Rule):
                 not isinstance(lam.body, ast.Tuple):
             return out
         params = {a.arg for a in (lam.args.posonlyargs + lam.args.args)}
+        if len(block.elts) == 1 and len(lam.body.elts) == 1:
+            dim, idx = block.elts[0], lam.body.elts[0]
+            tile = res.eval(dim)
+            moves = any(isinstance(n, ast.Name) and n.id in params
+                        for n in ast.walk(idx))
+            if moves and (tile is None or tile % 1024 != 0):
+                out.append(self.finding(
+                    ctx, dim if hasattr(dim, "lineno") else spec,
+                    f"rank-1 block ({ast.unparse(dim)},) is tiled along "
+                    "a grid axis: the TPU lowering needs a rank-1 block "
+                    "to be the whole vector or a multiple of 128 lanes "
+                    "(1024 to match XLA's layout of a long vector) — "
+                    "carry the vector as a 2-D (n, 1) or (1, n) block",
+                    symbol=f"rank1-block:{ast.unparse(dim)}",
+                    qualname=qual))
         for i, (dim, idx) in enumerate(zip(block.elts, lam.body.elts)):
             varies = isinstance(idx, ast.Name) and idx.id in params
             if not varies:

@@ -62,9 +62,8 @@ from dataclasses import dataclass, field
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.jaxcompat import shard_map_compat
 
 from repro.core.hashing import U32_MAX
 from repro.core.lsh import band_values
@@ -301,12 +300,12 @@ def make_streamed_dedup_step(cfg: DistLSHConfig, mesh: Mesh, *,
         bands = band_values(sig, cfg.rows_per_band)  # (D_loc, b, 2)
         return sig, bands
 
-    prepare = jax.jit(shard_map_compat(
+    prepare = jax.jit(shard_map(
         local_prepare,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P()),
         out_specs=(P(axis), P(axis)),
-        check_replication=False,
+        check_vma=False,
     ))
 
     def local_group(bands_g, sig, doc_offset):
@@ -401,12 +400,12 @@ def make_streamed_dedup_step(cfg: DistLSHConfig, mesh: Mesh, *,
     group_out_specs = (P(axis), P(axis), P(axis), P(axis))
     if stage2 == "device":
         group_out_specs = group_out_specs + (P(), P(), P(axis))
-    group_step = jax.jit(shard_map_compat(
+    group_step = jax.jit(shard_map(
         local_group,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=group_out_specs,
-        check_replication=False,
+        check_vma=False,
     ))
 
     def step(tokens, lengths, seeds, doc_offsets=None):

@@ -66,14 +66,6 @@ def signatures_np(
     return out
 
 
-def estimate_jaccard(sig_a: jnp.ndarray, sig_b: jnp.ndarray) -> jnp.ndarray:
-    """Signature-agreement Jaccard estimate (paper §3.4): m/M.
-
-    sig_a, sig_b: (..., M) uint32.
-    """
-    return jnp.mean((sig_a == sig_b).astype(jnp.float32), axis=-1)
-
-
 def minhash_from_tokens(
     tokens: jnp.ndarray,
     lengths: jnp.ndarray,

@@ -16,7 +16,8 @@ entirely over the immutable ``core.session.SessionView``:
 Estimator parity is load-bearing: the verify step reuses the engine's
 exact estimators bit-for-bit (``(a == b).mean`` in float32 for
 signature sessions — host numpy, or the fused
-``kernels.sigjaccard.indexed_pair_estimate`` gather kernel on device —
+``kernels.sigjaccard.indexed_pair_counts`` gather kernel on device with
+the /M in numpy —
 and the merge-count exact Jaccard for exact sessions), so querying an
 already-ingested document reproduces the session's recorded pair sims
 exactly.  Queries NEVER mutate session state: probes run over the
@@ -306,15 +307,9 @@ class ViewVerifier:
             bucket *= 2
         a_dev = jnp.asarray(np.pad(a_np, (0, bucket - p)))
         b_dev = jnp.asarray(np.pad(b_np, (0, bucket - p)))
-        if self.backend == "jnp":
-            from repro.core.verify import _gather_estimate_jit
+        from repro.core.verify import device_estimate
 
-            est = _gather_estimate_jit(stack, a_dev, b_dev)
-        else:
-            from repro.kernels import ops as kops
-
-            est = kops.indexed_pair_estimate(stack, a_dev, b_dev)
-        return np.asarray(est)[:p]
+        return device_estimate(self.backend, stack, a_dev, b_dev)[:p]
 
 
 class ExactViewVerifier:

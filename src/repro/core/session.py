@@ -1143,6 +1143,13 @@ class _ShardedBackend:
         self._step = None
         self.n_dev = int(np.prod([self.mesh.shape[a]
                                   for a in self.mesh.axis_names]))
+        # Chunk inputs go straight to their shards (and the seeds to
+        # every device) instead of landing whole on the default device.
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        self._rows = NamedSharding(self.mesh,
+                                   PartitionSpec(self.mesh.axis_names[0]))
+        self._replicated = NamedSharding(self.mesh, PartitionSpec())
 
     @property
     def stage2(self) -> str:
@@ -1154,6 +1161,15 @@ class _ShardedBackend:
 
             self._step = make_streamed_dedup_step(self.dcfg, self.mesh)
         return self._step
+
+    def _run_step(self, data, lengths, offsets):
+        import jax
+
+        rows = self._rows
+        return self._get_step()(
+            jax.device_put(data, rows), jax.device_put(lengths, rows),
+            jax.device_put(self.sess.seeds, self._replicated),
+            jax.device_put(offsets, rows))
 
     def dispatch(self, chunk, tokenized: bool = False):
         sess = self.sess
@@ -1172,9 +1188,7 @@ class _ShardedBackend:
         packed = shingle.pack_documents(padded)
         d_loc = len(padded) // self.n_dev
         offsets = DocIdAllocator.device_offsets(base, d_loc, self.n_dev)
-        out = self._get_step()(
-            jnp.asarray(packed.tokens), jnp.asarray(packed.lengths),
-            jnp.asarray(sess.seeds), jnp.asarray(offsets))
+        out = self._run_step(packed.tokens, packed.lengths, offsets)
         return (base, toks, n_real, out)
 
     def _dispatch_bytes(self, chunk, tokenized: bool):
@@ -1198,9 +1212,7 @@ class _ShardedBackend:
         packed = shingle.pack_bytes(padded, blen)
         d_loc = len(padded) // self.n_dev
         offsets = DocIdAllocator.device_offsets(base, d_loc, self.n_dev)
-        out = self._get_step()(
-            jnp.asarray(packed.data), jnp.asarray(packed.lengths),
-            jnp.asarray(sess.seeds), jnp.asarray(offsets))
+        out = self._run_step(packed.data, packed.lengths, offsets)
         return (base, docs, n_real, out)
 
     def merge(self, pending):

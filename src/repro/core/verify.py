@@ -15,8 +15,9 @@ verifier             computes
 ===================  =====================================================
 SignatureVerifier    signature-agreement estimate m/M (paper §3.4) over
                      gathered signature rows; backend ``numpy`` (host),
-                     ``jnp`` (``minhash.estimate_jaccard`` under jit) or
-                     ``pallas`` (``kernels.sigjaccard.pair_estimate``)
+                     ``jnp`` (gather + count under jit) or ``pallas``
+                     (``kernels.sigjaccard.indexed_pair_counts``); the
+                     device counts, numpy divides by M
 ExactJaccardVerifier exact set Jaccard (paper §2.1) vectorized over
                      pre-sorted n-gram id arrays (merge-count, no
                      Python set ops on the hot path)
@@ -41,8 +42,7 @@ from typing import Callable
 
 import numpy as np
 import jax
-
-from repro.core import minhash
+import jax.numpy as jnp
 
 
 class BatchVerifier:
@@ -102,11 +102,13 @@ class SignatureVerifier(BatchVerifier):
 
     ``backend``:
       * ``"numpy"`` — host vectorized ``(sig[a] == sig[b]).mean(-1)``.
-      * ``"jnp"``   — jitted gather + ``minhash.estimate_jaccard`` on
-        device; batches are padded to power-of-two buckets so the jit
-        cache stays small.
-      * ``"pallas"`` — ``kernels.sigjaccard.pair_estimate`` TPU kernel
-        (interpret mode on CPU), same shape bucketing.
+      * ``"jnp"``   — jitted gather + agreement count on device;
+        batches are padded to power-of-two buckets so the jit cache
+        stays small.
+      * ``"pallas"`` — ``kernels.sigjaccard.indexed_pair_counts`` TPU
+        kernel (interpret mode on CPU), same shape bucketing.
+      Both device backends divide the counts by M in numpy
+      (``device_estimate``), bit-identical to ``"numpy"``.
     """
 
     def __init__(self, signatures: np.ndarray, backend: str = "numpy",
@@ -319,19 +321,29 @@ class SignatureVerifier(BatchVerifier):
         a_idx = jnp.asarray(np.pad(a_idx, (0, bucket - p)))
         b_idx = jnp.asarray(np.pad(b_idx, (0, bucket - p)))
         sig_dev = self._device_signatures()
-        if self.backend == "jnp":
-            est = _gather_estimate_jit(sig_dev, a_idx, b_idx)
-        else:
-            from repro.kernels import ops as kops
-
-            est = kops.indexed_pair_estimate(sig_dev, a_idx, b_idx)
-        return np.asarray(est)[:p]
+        return device_estimate(self.backend, sig_dev, a_idx, b_idx)[:p]
 
 
 @jax.jit
-def _gather_estimate_jit(sig, a_idx, b_idx):
-    """Fused gather + agreement estimate (one dispatch per bucket)."""
-    return minhash.estimate_jaccard(sig[a_idx], sig[b_idx])
+def _gather_counts_jit(sig, a_idx, b_idx):
+    """Fused gather + agreement counts (one dispatch per bucket)."""
+    return jnp.sum((sig[a_idx] == sig[b_idx]).astype(jnp.float32), axis=-1)
+
+
+def device_estimate(backend: str, sig_dev, a_idx, b_idx) -> np.ndarray:
+    """m/M estimates of (a, b) row pairs, agreement counted on device.
+
+    The device returns exact counts and numpy divides by M, so the
+    ``jnp`` and ``pallas`` backends are bit-identical to the ``numpy``
+    estimator (a division on the device is not correctly rounded).
+    """
+    if backend == "jnp":
+        counts = _gather_counts_jit(sig_dev, a_idx, b_idx)
+    else:
+        from repro.kernels import ops as kops
+
+        counts = kops.indexed_pair_counts(sig_dev, a_idx, b_idx)
+    return np.asarray(counts) / np.float32(sig_dev.shape[1])
 
 
 class ShardedEdgeVerifier(SignatureVerifier):

@@ -13,19 +13,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.hashing import GOLDEN32
+from repro.core.hashing import GOLDEN32, fmix32
+from repro.kernels.common import resolve_interpret
 
 _LANE_SEEDS = (0x2545F491, 0x9E3779B9)
 TD, TB = 64, 64
-
-
-def _fmix(x):
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    return x
 
 
 def _bandfold_kernel(sig_ref, out_ref, *, r: int):
@@ -33,7 +25,7 @@ def _bandfold_kernel(sig_ref, out_ref, *, r: int):
     for lane, seed in enumerate(_LANE_SEEDS):
         h = jnp.full(sig.shape[:2], jnp.uint32(seed), dtype=jnp.uint32)
         for k in range(r):
-            h = _fmix(h * GOLDEN32 + sig[:, :, k])
+            h = fmix32(h * GOLDEN32 + sig[:, :, k])
         out_ref[:, :, lane] = h
 
 
@@ -47,8 +39,7 @@ def band_values(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """(D, M) uint32 signatures -> (D, b, 2) uint32 band values."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     D, M = sig.shape
     assert M % r == 0
     b = M // r

@@ -13,21 +13,24 @@ per-token FNV-1a over folded bytes reproduces
 ``token_ids(tokenize(text, do_stem=False))`` exactly; multi-byte safety
 is structural (no token byte can sit inside a multi-byte sequence).
 
-FNV-1a is sequential per token, so the kernel scans byte columns with a
-``jax.lax.scan`` carrying (FNV state, prev-byte-was-alnum) per document
-row.  The carries persist across L tiles as revisited rank-1 output
-blocks (the ``fused_ingest`` signature-accumulator idiom: the grid's
-last axis is sequential on TPU, so the (TD,) carry block stays resident
+FNV-1a is sequential per token, so the kernel walks byte positions one
+at a time, carrying (FNV state, prev-byte-was-alnum) per document.  The
+byte matrix is transposed on the way in: positions run down the rows and
+documents across the 128 lanes, so each step updates 128 documents at
+once and the loop indexes the major axis (Mosaic lowers no scan over
+a lane axis).  The carries persist across L tiles as revisited (1, TD)
+output blocks (the ``fused_ingest`` signature-accumulator idiom: the
+grid's last axis is sequential on TPU, so the carry block stays resident
 in VMEM across the L revisits) and are re-initialized at the first L
 tile.  Zero padding is a separator, so a token ending at the last byte
 of a document emits at the following zero column — callers must keep
 matrix width strictly greater than every byte length (``pack_bytes``
 enforces this; ``bytes_to_bands`` also pads one extra column).
 
-Grid (D/TD, LB/TLB), L innermost.  VMEM per step is one (TD, TLB) uint8
-byte tile + the uint32 token/end tiles + two (TD,) carries — well under
-budget; nothing per-token ever reaches HBM except the compacted token
-matrix handed to ``fused_ingest``.
+Grid (D/TD, LB/TLB), L innermost.  VMEM per step is one (TLB, TD) uint8
+byte tile, its int32 copy, the uint32 token/end tiles and two (1, TD)
+carries.  The per-position token/end matrices go back to HBM, where
+XLA compacts them into the token matrix handed to ``fused_ingest``.
 """
 from __future__ import annotations
 
@@ -36,65 +39,73 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.hashing import FNV_OFFSET32, FNV_PRIME32, GOLDEN32
+from repro.core.hashing import FNV_OFFSET32, FNV_PRIME32, GOLDEN32, fmix32
+from repro.kernels.common import resolve_interpret
 from repro.kernels.fused_ingest import fused_ingest
 
 # Default seed of core.shingle.token_ids (the hash-vocabulary seed).
 TOKEN_SEED = 0x7045
 
-# Default tiles: (TD, TLB) uint8 + uint32 outputs ~ 18 KiB VMEM.
-TD, TLB = 8, 256
+# Default tiles: 128 documents (one lane width) x 256 byte positions.
+TD, TLB = 128, 256
+_ROWS = 8  # byte rows per loop step: one (8, 128) int32 vreg of docs
 
 
-def _fmix(x):
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    return x
+def _byte_kernel(byte_ref, len_ref, tok_ref, end_ref, h_ref, p_ref,
+                 col_ref, *, tlb: int, seed: int):
+    """One (TLB bytes, TD docs) tile; byte positions run down the rows.
 
-
-def _byte_kernel(byte_ref, len_ref, tok_ref, end_ref, h_ref, p_ref, *,
-                 td: int, tlb: int, seed: int):
+    Documents sit on the lane axis, so the FNV chain walks the major
+    (row) axis: a ``fori_loop`` over groups of 8 rows reads aligned
+    (8, TD) slabs of the int32 copy of the tile, and the (1, TD)
+    carries live in revisited output blocks across the L tiles.
+    """
     l_idx = pl.program_id(1)
 
     @pl.when(l_idx == 0)
     def _init():
-        h_ref[...] = jnp.full((td,), jnp.uint32(FNV_OFFSET32),
+        h_ref[...] = jnp.full(h_ref.shape, jnp.uint32(FNV_OFFSET32),
                               dtype=jnp.uint32)
-        p_ref[...] = jnp.zeros((td,), dtype=jnp.uint32)
+        p_ref[...] = jnp.zeros(p_ref.shape, dtype=jnp.int32)
 
-    cols = byte_ref[...].astype(jnp.uint32).T      # (TLB, TD)
-    lens = len_ref[...].astype(jnp.int32)          # (TD,)
-    # Positions at or beyond a document's byte length are separators, so
-    # garbage padding never leaks into tokens.
-    pos = l_idx * tlb + jax.lax.broadcasted_iota(jnp.int32, (td, tlb), 1)
-    in_doc = (pos < lens[:, None]).T               # (TLB, TD)
+    col_ref[...] = byte_ref[...].astype(jnp.int32)
+    lens = len_ref[...]                               # (1, TD)
+    offset = jnp.uint32(FNV_OFFSET32)
+    prime = jnp.uint32(FNV_PRIME32)
 
-    def step(carry, xs):
-        h, prev = carry
-        b, live = xs
-        upper = (b >= jnp.uint32(65)) & (b <= jnp.uint32(90))
-        alnum = (upper
-                 | ((b >= jnp.uint32(97)) & (b <= jnp.uint32(122)))
-                 | ((b >= jnp.uint32(48)) & (b <= jnp.uint32(57)))) & live
-        folded = jnp.where(upper, b + jnp.uint32(32), b)
-        # A run restarts from the FNV offset basis at its first byte.
-        h0 = jnp.where(prev > jnp.uint32(0), h, jnp.uint32(FNV_OFFSET32))
-        h_new = jnp.where(alnum, (h0 ^ folded) * jnp.uint32(FNV_PRIME32), h)
-        end = (prev > jnp.uint32(0)) & jnp.logical_not(alnum)
-        tok = jnp.where(end, _fmix(h * GOLDEN32 + jnp.uint32(seed)),
-                        jnp.uint32(0))
-        return (h_new, alnum.astype(jnp.uint32)), (tok, end.astype(jnp.int32))
+    def group(g, carry):
+        h, prev = carry                               # (1, TD) each
+        row0 = pl.multiple_of(g * _ROWS, _ROWS)
+        slab = col_ref[pl.ds(row0, _ROWS), :]         # (8, TD) int32
+        toks, ends = [], []
+        for j in range(_ROWS):
+            b = slab[j:j + 1, :]
+            # Positions at or beyond a document's byte length are
+            # separators, so garbage padding never leaks into tokens.
+            live = l_idx * tlb + row0 + j < lens
+            upper = (b >= 65) & (b <= 90)
+            alnum = (upper | ((b >= 97) & (b <= 122))
+                     | ((b >= 48) & (b <= 57))) & live
+            folded = jnp.where(upper, b + 32, b).astype(jnp.uint32)
+            in_run = prev > 0
+            # A run restarts from the FNV offset basis at its first byte.
+            h0 = jnp.where(in_run, h, offset)
+            end = in_run & jnp.logical_not(alnum)
+            toks.append(jnp.where(
+                end, fmix32(h * GOLDEN32 + jnp.uint32(seed)), jnp.uint32(0)))
+            ends.append(end.astype(jnp.int32))
+            h = jnp.where(alnum, (h0 ^ folded) * prime, h)
+            prev = alnum.astype(jnp.int32)
+        tok_ref[pl.ds(row0, _ROWS), :] = jnp.concatenate(toks, axis=0)
+        end_ref[pl.ds(row0, _ROWS), :] = jnp.concatenate(ends, axis=0)
+        return h, prev
 
-    (h_fin, p_fin), (toks, ends) = jax.lax.scan(
-        step, (h_ref[...], p_ref[...]), (cols, in_doc))
-    tok_ref[...] = toks.T
-    end_ref[...] = ends.T
-    h_ref[...] = h_fin
-    p_ref[...] = p_fin
+    h, prev = jax.lax.fori_loop(0, tlb // _ROWS, group,
+                                (h_ref[...], p_ref[...]))
+    h_ref[...] = h
+    p_ref[...] = prev
 
 
 @functools.partial(
@@ -117,8 +128,7 @@ def byte_token_hashes(
     must exceed every byte length (a token touching the last column
     would have nowhere to emit) — ``pack_bytes`` guarantees this.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     data = data.astype(jnp.uint8)
     lengths = lengths.astype(jnp.int32)
     D, LB = data.shape
@@ -126,36 +136,41 @@ def byte_token_hashes(
         return (jnp.zeros((0, LB), jnp.uint32),
                 jnp.zeros((0, LB), jnp.int32))
     td_ = min(td, max(1, D))
+    # Byte rows are walked 8 at a time, so the tile rounds up to 8 rows
+    # (on the TPU a uint8 tile must also be a multiple of 32 rows).
     tlb_ = min(tlb, max(1, LB))
+    tlb_ = -(-tlb_ // _ROWS) * _ROWS
     Dp = -(-D // td_) * td_
     Lp = -(-LB // tlb_) * tlb_
-    buf = jnp.pad(data, ((0, Dp - D), (0, Lp - LB)))
-    ln = jnp.pad(lengths, (0, Dp - D))
+    # Transposed: byte positions down the rows, documents across lanes.
+    buf = jnp.pad(data, ((0, Dp - D), (0, Lp - LB))).T
+    ln = jnp.pad(lengths, (0, Dp - D))[None, :]
 
     tok, ends, _, _ = pl.pallas_call(
-        functools.partial(_byte_kernel, td=td_, tlb=tlb_, seed=id_seed),
+        functools.partial(_byte_kernel, tlb=tlb_, seed=id_seed),
         grid=(Dp // td_, Lp // tlb_),
         in_specs=[
-            pl.BlockSpec((td_, tlb_), lambda d, l: (d, l)),
-            pl.BlockSpec((td_,), lambda d, l: (d,)),
+            pl.BlockSpec((tlb_, td_), lambda d, l: (l, d)),
+            pl.BlockSpec((1, td_), lambda d, l: (0, d)),
         ],
         out_specs=[
-            pl.BlockSpec((td_, tlb_), lambda d, l: (d, l)),
-            pl.BlockSpec((td_, tlb_), lambda d, l: (d, l)),
-            # FNV-state / prev-alnum carries: revisited rank-1 blocks,
+            pl.BlockSpec((tlb_, td_), lambda d, l: (l, d)),
+            pl.BlockSpec((tlb_, td_), lambda d, l: (l, d)),
+            # FNV-state / in-run carries: revisited (1, TD) blocks,
             # VMEM-resident across the sequential L axis.
-            pl.BlockSpec((td_,), lambda d, l: (d,)),
-            pl.BlockSpec((td_,), lambda d, l: (d,)),
+            pl.BlockSpec((1, td_), lambda d, l: (0, d)),
+            pl.BlockSpec((1, td_), lambda d, l: (0, d)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Dp, Lp), jnp.uint32),
-            jax.ShapeDtypeStruct((Dp, Lp), jnp.int32),
-            jax.ShapeDtypeStruct((Dp,), jnp.uint32),
-            jax.ShapeDtypeStruct((Dp,), jnp.uint32),
+            jax.ShapeDtypeStruct((Lp, Dp), jnp.uint32),
+            jax.ShapeDtypeStruct((Lp, Dp), jnp.int32),
+            jax.ShapeDtypeStruct((1, Dp), jnp.uint32),
+            jax.ShapeDtypeStruct((1, Dp), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((tlb_, td_), jnp.int32)],
         interpret=interpret,
     )(buf, ln)
-    return tok[:D, :LB], ends[:D, :LB]
+    return tok.T[:D, :LB], ends.T[:D, :LB]
 
 
 @functools.partial(

@@ -38,7 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.hashing import GOLDEN32, NGRAM_BASE, U32_MAX
+from repro.core.hashing import GOLDEN32, NGRAM_BASE, U32_MAX, fmix32
+from repro.kernels.common import resolve_interpret, umin, uminimum
 
 _LANE_SEEDS = (0x2545F491, 0x9E3779B9)
 
@@ -46,42 +47,35 @@ _LANE_SEEDS = (0x2545F491, 0x9E3779B9)
 TD, TL, TM = 8, 128, 128
 
 
-def _fmix(x):
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    return x
-
-
 def _fused_kernel(tok_ref, halo_ref, len_ref, seeds_ref, sig_ref,
                   band_ref, *, n: int, r: int, td: int, tl: int,
                   tm: int, n_l: int):
     l_idx = pl.program_id(2)
-    tok = tok_ref[...].astype(jnp.uint32)     # (TD, TL)
-    halo = halo_ref[...].astype(jnp.uint32)   # (TD, TL) next tile (clamped)
-    lens = len_ref[...].astype(jnp.int32)     # (TD,)
-    seeds = seeds_ref[...].astype(jnp.uint32)  # (TM,)
+    tok = tok_ref[...]                         # (TD, TL)
+    halo = halo_ref[...]                       # (TD, TL) next tile (clamped)
+    ln = len_ref[...]                          # (TD, 1)
+    seeds = seeds_ref[...]                     # (1, TM), band-major order
 
-    # --- shingle: rolling n-gram polynomial hash over the halo'd tile.
+    # --- shingle: rolling n-gram polynomial hash over the halo'd tile
+    # (static lane slices; Mosaic has no dynamic_slice).
     cat = jnp.concatenate([tok, halo], axis=1)
     acc = jnp.zeros_like(tok)
     base = jnp.uint32(NGRAM_BASE)
     for k in range(n):
-        acc = acc * base + jax.lax.dynamic_slice_in_dim(cat, k, tl, axis=1)
-    ng = _fmix(acc)                            # (TD, TL), VMEM-only
+        acc = acc * base + cat[:, k:k + tl]
+    ng = fmix32(acc)                            # (TD, TL), VMEM-only
 
     # Validity of each window position (incl. the short-doc single
     # shingle at position 0), from lengths alone — no mask operand.
     pos = l_idx * tl + jax.lax.broadcasted_iota(jnp.int32, (td, tl), 1)
-    ln = lens[:, None]
     valid = (pos + n <= ln) | ((ln < n) & (pos == 0) & (ln > 0))
 
     # --- minhash: seeded cube, min-accumulate into the resident block.
-    x = _fmix(ng[:, :, None] * GOLDEN32 + seeds[None, None, :])
-    x = jnp.where(valid[:, :, None], x, jnp.uint32(U32_MAX))
-    part = jnp.min(x, axis=1)                  # (TD, TM)
+    # Invalid positions OR to all-ones (U32_MAX), which never wins a min;
+    # a uint32 mask, because Mosaic cannot reshape a boolean tile to 3-D.
+    dead = jnp.where(valid, jnp.uint32(0), jnp.uint32(U32_MAX))
+    x = fmix32(ng[:, :, None] * GOLDEN32 + seeds[None, :, :])
+    part = umin(x | dead[:, :, None], axis=1)  # (TD, TM)
 
     @pl.when(l_idx == 0)
     def _init():
@@ -89,19 +83,21 @@ def _fused_kernel(tok_ref, halo_ref, len_ref, seeds_ref, sig_ref,
 
     @pl.when(l_idx > 0)
     def _acc():
-        sig_ref[...] = jnp.minimum(sig_ref[...], part)
+        sig_ref[...] = uminimum(sig_ref[...], part)
 
     # --- band fold: the signature block is final on the last L tile;
-    # fold its bands in-register (tm % r == 0 by construction).
+    # fold its bands in-register (tm % r == 0 by construction).  The
+    # band-major column order turns row k of every band into one
+    # contiguous lane slice.
     @pl.when(l_idx == n_l - 1)
     def _fold():
-        s3 = sig_ref[...].reshape(td, tm // r, r)
+        s = sig_ref[...]
+        bt = tm // r
         for lane, seed in enumerate(_LANE_SEEDS):
-            h = jnp.full((td, tm // r), jnp.uint32(seed),
-                         dtype=jnp.uint32)
+            h = jnp.full((td, bt), jnp.uint32(seed), dtype=jnp.uint32)
             for k in range(r):
-                h = _fmix(h * GOLDEN32 + s3[:, :, k])
-            band_ref[:, :, lane] = h
+                h = fmix32(h * GOLDEN32 + s[:, k * bt:(k + 1) * bt])
+            band_ref[lane] = h
 
 
 @functools.partial(
@@ -127,8 +123,7 @@ def fused_ingest(
     shorter than ``n`` are handled (the tile length is clamped up to
     ``n`` and the zero right-padding reproduces the short-doc rule).
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     tokens = tokens.astype(jnp.uint32)
     lengths = lengths.astype(jnp.int32)
     seeds = seeds.astype(jnp.uint32)
@@ -144,16 +139,25 @@ def fused_ingest(
     # The halo read needs tl >= n (a window crosses at most one tile
     # boundary); clamping up also absorbs batches with L < n.
     tl_ = max(min(tl, max(1, L)), n)
-    # Every band's r rows must fall inside one M tile.
+    # Every band's r rows must fall inside one M tile.  On the TPU a
+    # band block narrower than M must be a multiple of 128 lanes; the
+    # default tile holds all of M (the paper's M=100) in one.
     tm_ = min(tm, max(1, M))
     tm_ = max(r, (tm_ // r) * r)
     Dp = -(-D // td_) * td_
     Lp = -(-L // tl_) * tl_
     Mp = -(-M // tm_) * tm_
     tok = jnp.pad(tokens, ((0, Dp - D), (0, Lp - L)))
-    ln = jnp.pad(lengths, (0, Dp - D))
-    sd = jnp.pad(seeds, (0, Mp - M))
+    # Lengths ride as a (Dp, 1) column: a rank-1 (td,) block is not a
+    # legal TPU block unless it is 128-aligned or the whole array.
+    ln = jnp.pad(lengths, (0, Dp - D))[:, None]
     n_l = Lp // tl_
+    n_m = Mp // tm_
+    bt = tm_ // r
+    # Band-major seed order inside each M tile: column k*bt + j holds
+    # signature row j*r + k, so the fold reads contiguous lane slices.
+    sd = jnp.pad(seeds, (0, Mp - M)).reshape(n_m, bt, r)
+    sd = sd.transpose(0, 2, 1).reshape(1, Mp)
 
     sig, bands = pl.pallas_call(
         functools.partial(_fused_kernel, n=n, r=r, td=td_, tl=tl_,
@@ -166,19 +170,21 @@ def fused_ingest(
             pl.BlockSpec(
                 (td_, tl_),
                 lambda d, m, l: (d, jnp.minimum(l + 1, n_l - 1))),
-            pl.BlockSpec((td_,), lambda d, m, l: (d,)),
-            pl.BlockSpec((tm_,), lambda d, m, l: (m,)),
+            pl.BlockSpec((td_, 1), lambda d, m, l: (d, 0)),
+            pl.BlockSpec((1, tm_), lambda d, m, l: (0, m)),
         ],
         out_specs=[
             pl.BlockSpec((td_, tm_), lambda d, m, l: (d, m)),
-            pl.BlockSpec((td_, tm_ // r, 2), lambda d, m, l: (d, m, 0)),
+            pl.BlockSpec((2, td_, tm_ // r), lambda d, m, l: (0, d, m)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Dp, Mp), jnp.uint32),
-            jax.ShapeDtypeStruct((Dp, Mp // r, 2), jnp.uint32),
+            jax.ShapeDtypeStruct((2, Dp, Mp // r), jnp.uint32),
         ],
         interpret=interpret,
     )(tok, tok, ln, sd)
+    sig = sig.reshape(Dp, n_m, r, bt).transpose(0, 1, 3, 2).reshape(Dp, Mp)
+    bands = bands.transpose(1, 2, 0)             # (Dp, Mp // r, 2)
 
     pos = jnp.arange(L, dtype=jnp.int32)[None, :]
     ln2 = lengths[:, None]

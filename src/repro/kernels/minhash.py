@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.hashing import GOLDEN32, U32_MAX
+from repro.core.hashing import GOLDEN32, U32_MAX, fmix32
+from repro.kernels.common import resolve_interpret, umin, uminimum
 
 # Default tile sizes: (TD, TL, TM) cube = 8*128*128*4B = 512 KiB in VMEM.
 TD, TL, TM = 8, 128, 128
@@ -27,19 +28,14 @@ TD, TL, TM = 8, 128, 128
 
 def _minhash_kernel(ng_ref, valid_ref, seeds_ref, out_ref):
     l_idx = pl.program_id(2)
-    ng = ng_ref[...].astype(jnp.uint32)          # (TD, TL)
+    ng = ng_ref[...]                              # (TD, TL)
     valid = valid_ref[...]                        # (TD, TL) uint32 0/1
-    seeds = seeds_ref[...].astype(jnp.uint32)     # (TM,)
+    seeds = seeds_ref[...]                        # (1, TM)
 
-    x = ng[:, :, None] * GOLDEN32 + seeds[None, None, :]
-    # fmix32 inline (Murmur3 finalizer) — 32-bit ops only.
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    x = jnp.where(valid[:, :, None] != 0, x, jnp.uint32(U32_MAX))
-    part = jnp.min(x, axis=1)                     # (TD, TM)
+    x = fmix32(ng[:, :, None] * GOLDEN32 + seeds[None, :, :])
+    # Invalid positions OR to all-ones (U32_MAX), which never wins a min.
+    dead = jnp.where(valid != 0, jnp.uint32(0), jnp.uint32(U32_MAX))
+    part = umin(x | dead[:, :, None], axis=1)     # (TD, TM)
 
     @pl.when(l_idx == 0)
     def _init():
@@ -47,7 +43,7 @@ def _minhash_kernel(ng_ref, valid_ref, seeds_ref, out_ref):
 
     @pl.when(l_idx > 0)
     def _acc():
-        out_ref[...] = jnp.minimum(out_ref[...], part)
+        out_ref[...] = uminimum(out_ref[...], part)
 
 
 @functools.partial(
@@ -64,8 +60,7 @@ def minhash_signatures(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """(D, L) uint32 n-gram hashes + (D, L) validity -> (D, M) signatures."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     D, L = ngrams.shape
     M = seeds.shape[0]
     td = min(td, max(1, D))
@@ -74,7 +69,7 @@ def minhash_signatures(
     Dp, Lp, Mp = -(-D // td) * td, -(-L // tl) * tl, -(-M // tm) * tm
     ng = jnp.pad(ngrams.astype(jnp.uint32), ((0, Dp - D), (0, Lp - L)))
     vd = jnp.pad(valid.astype(jnp.uint32), ((0, Dp - D), (0, Lp - L)))
-    sd = jnp.pad(seeds.astype(jnp.uint32), (0, Mp - M))
+    sd = jnp.pad(seeds.astype(jnp.uint32), (0, Mp - M))[None, :]
 
     out = pl.pallas_call(
         _minhash_kernel,
@@ -82,7 +77,7 @@ def minhash_signatures(
         in_specs=[
             pl.BlockSpec((td, tl), lambda d, m, l: (d, l)),
             pl.BlockSpec((td, tl), lambda d, m, l: (d, l)),
-            pl.BlockSpec((tm,), lambda d, m, l: (m,)),
+            pl.BlockSpec((1, tm), lambda d, m, l: (0, m)),
         ],
         out_specs=pl.BlockSpec((td, tm), lambda d, m, l: (d, m)),
         out_shape=jax.ShapeDtypeStruct((Dp, Mp), jnp.uint32),

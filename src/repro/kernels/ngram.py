@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.hashing import NGRAM_BASE
+from repro.core.hashing import NGRAM_BASE, fmix32
+from repro.kernels.common import resolve_interpret
 
 TD, TL = 8, 256
 
@@ -24,16 +25,9 @@ def _ngram_kernel(tok_ref, halo_ref, out_ref, *, n: int, tl: int):
     cat = jnp.concatenate([tok, halo], axis=1)
     acc = jnp.zeros_like(tok)
     base = jnp.uint32(NGRAM_BASE)
-    for k in range(n):
-        acc = acc * base + jax.lax.dynamic_slice_in_dim(cat, k, tl, axis=1)
-    # fmix32
-    x = acc
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> 13)
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> 16)
-    out_ref[...] = x
+    for k in range(n):  # static lane slices: Mosaic has no dynamic_slice
+        acc = acc * base + cat[:, k:k + tl]
+    out_ref[...] = fmix32(acc)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "td", "tl", "interpret"))
@@ -51,8 +45,7 @@ def ngram_hashes(
     Matches ``repro.core.shingle.ngram_hashes`` (the ref oracle), including
     the short-document single-shingle rule.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     D, L = tokens.shape
     td_ = min(td, max(1, D))
     # Clamp the L tile UP to n: a batch narrower than the window pads to

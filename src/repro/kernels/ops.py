@@ -12,11 +12,11 @@ from repro.kernels.bandfold import band_values
 from repro.kernels.fused_ingest import fused_ingest
 from repro.kernels.byte_shingle import byte_token_hashes, bytes_to_bands
 from repro.kernels.sigjaccard import (
-    indexed_pair_estimate,
+    indexed_pair_counts,
     masked_indexed_pair_counts,
     masked_indexed_pair_estimate,
     masked_pair_counts,
-    pair_estimate,
+    pair_counts,
 )
 from repro.kernels.flash_attention import flash_attention
 
@@ -27,8 +27,8 @@ __all__ = [
     "fused_ingest",
     "byte_token_hashes",
     "bytes_to_bands",
-    "pair_estimate",
-    "indexed_pair_estimate",
+    "pair_counts",
+    "indexed_pair_counts",
     "masked_indexed_pair_counts",
     "masked_indexed_pair_estimate",
     "masked_pair_counts",

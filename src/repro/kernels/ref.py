@@ -24,8 +24,8 @@ def band_values(sig, r: int):
     return _lsh.band_values(sig, r)
 
 
-def pair_estimate(sig_a, sig_b):
-    return jnp.mean((sig_a == sig_b).astype(jnp.float32), axis=-1)
+def pair_counts(sig_a, sig_b):
+    return jnp.sum((sig_a == sig_b).astype(jnp.float32), axis=-1)
 
 
 def fused_ingest(tokens, lengths, seeds, *, n: int = 8, r: int = 2):

@@ -1,8 +1,19 @@
-"""Pallas TPU kernel: signature-agreement Jaccard estimate for pairs.
+"""Pallas TPU kernel: signature-agreement counts for candidate pairs.
 
-Given pre-gathered signature rows for P candidate pairs, computes
-est[p] = mean_m( a[p, m] == b[p, m] )  (paper §3.4's m/M estimator).
-Memory-bound; tiled (TP, M) so both operands stream through VMEM once.
+Given signature rows for P candidate pairs, computes the exact count
+c[p] = #{m : a[p, m] == b[p, m]} as float32 (an exact integer value);
+paper §3.4's m/M estimate is c / M.  Memory-bound; tiled (TP, M) so both
+operands stream through VMEM once.
+
+The kernels emit counts, never the estimate: a float32 c / M computed
+on the device is not the host estimator's correctly rounded division
+(XLA turns division by a constant into a multiply by its reciprocal,
+which is 1 ulp off for 30 of the 101 counts at M=100).  Consumers divide
+in numpy, so every verify backend is bit-identical to the numpy one.
+
+Per-pair vectors (the output, the validity mask) are (P, 1) columns:
+XLA lays a rank-1 vector out in 1024-element tiles, which a (TP,) block
+does not match, while a (TP, 1) block is legal for any TP.
 """
 from __future__ import annotations
 
@@ -10,59 +21,67 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+
+from repro.kernels.common import resolve_interpret
 
 TP = 256
 
 
-def _sigjac_kernel(a_ref, b_ref, out_ref, *, m: int):
-    a = a_ref[...]
-    b = b_ref[...]
-    eq = (a == b).astype(jnp.float32)
-    out_ref[...] = jnp.sum(eq, axis=1) * (1.0 / m)
+def _counts_kernel(a_ref, b_ref, v_ref, out_ref):
+    eq = (a_ref[...] == b_ref[...]).astype(jnp.float32)
+    out_ref[...] = jnp.where(v_ref[...] != 0,
+                             jnp.sum(eq, axis=1, keepdims=True), 0.0)
 
 
-def _estimate(sig_a, sig_b, tp: int, interpret: bool | None):
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+def _counts_rows(sig_a, sig_b, valid, tp: int, interpret: bool | None):
+    """Masked agreement counts over PRE-GATHERED (P, M) row operands."""
+    interpret = resolve_interpret(interpret)
     P, M = sig_a.shape
     tp_ = min(tp, max(1, P))
     Pp = -(-P // tp_) * tp_
     a = jnp.pad(sig_a.astype(jnp.uint32), ((0, Pp - P), (0, 0)))
     b = jnp.pad(sig_b.astype(jnp.uint32), ((0, Pp - P), (0, 0)))
-    # Make padded rows disagree so padding can't look like a match.
-    if Pp > P:
-        row = jnp.arange(Pp)[:, None] >= P
-        b = jnp.where(row, b + jnp.uint32(1), b)
+    v = jnp.pad(valid.astype(jnp.int32), (0, Pp - P))[:, None]
 
     out = pl.pallas_call(
-        functools.partial(_sigjac_kernel, m=M),
+        _counts_kernel,
         grid=(Pp // tp_,),
         in_specs=[
             pl.BlockSpec((tp_, M), lambda p: (p, 0)),
             pl.BlockSpec((tp_, M), lambda p: (p, 0)),
+            pl.BlockSpec((tp_, 1), lambda p: (p, 0)),
         ],
-        out_specs=pl.BlockSpec((tp_,), lambda p: (p,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), jnp.float32),
+        out_specs=pl.BlockSpec((tp_, 1), lambda p: (p, 0)),
+        out_shape=jax.ShapeDtypeStruct((Pp, 1), jnp.float32),
         interpret=interpret,
-    )(a, b)
-    return out[:P]
+    )(a, b, v)
+    return out[:P, 0]
+
+
+def _gather_rows(sig, a_idx, b_idx):
+    """Row gather with indices clipped to the local row range."""
+    D = sig.shape[0]
+    return (sig[jnp.clip(a_idx, 0, D - 1)],
+            sig[jnp.clip(b_idx, 0, D - 1)])
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "interpret"))
-def pair_estimate(
+def pair_counts(
     sig_a: jnp.ndarray,
     sig_b: jnp.ndarray,
     *,
     tp: int = TP,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """(P, M), (P, M) uint32 -> (P,) float32 agreement fraction."""
-    return _estimate(sig_a, sig_b, tp, interpret)
+    """(P, M), (P, M) uint32 -> (P,) float32 agreement counts."""
+    valid = jnp.ones((sig_a.shape[0],), jnp.int32)
+    return _counts_rows(sig_a, sig_b, valid, tp, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "interpret"))
-def indexed_pair_estimate(
+def indexed_pair_counts(
     sig: jnp.ndarray,
     a_idx: jnp.ndarray,
     b_idx: jnp.ndarray,
@@ -70,56 +89,16 @@ def indexed_pair_estimate(
     tp: int = TP,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Fused gather + pair estimate: one dispatch per index batch.
+    """Fused gather + agreement counts: one dispatch per index batch.
 
-    sig (D, M) uint32, a_idx/b_idx (P,) int -> (P,) float32.  The row
-    gather runs on device inside the same jit as the kernel, so
-    verifiers never materialize the gathered operands on the host.
+    sig (D, M) uint32, a_idx/b_idx (P,) int -> (P,) float32 counts.
+    The row gather runs on device inside the same jit as the kernel, so
+    verifiers never materialize the gathered operands on the host; they
+    divide the counts by M in numpy.
     """
-    return _estimate(sig[a_idx], sig[b_idx], tp, interpret)
-
-
-def _sigjac_masked_kernel(a_ref, b_ref, v_ref, out_ref):
-    a = a_ref[...]
-    b = b_ref[...]
-    eq = (a == b).astype(jnp.float32)
-    out_ref[...] = jnp.where(v_ref[...] != 0, jnp.sum(eq, axis=1), 0.0)
-
-
-def _masked_counts_rows(sig_a, sig_b, valid, tp: int,
-                        interpret: bool | None):
-    """Masked agreement counts over PRE-GATHERED (P, M) row operands."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    P, M = sig_a.shape
-    tp_ = min(tp, max(1, P))
-    Pp = -(-P // tp_) * tp_
-    a = jnp.pad(sig_a.astype(jnp.uint32), ((0, Pp - P), (0, 0)))
-    b = jnp.pad(sig_b.astype(jnp.uint32), ((0, Pp - P), (0, 0)))
-    v = jnp.pad(valid.astype(jnp.int32), (0, Pp - P))
-
-    out = pl.pallas_call(
-        _sigjac_masked_kernel,
-        grid=(Pp // tp_,),
-        in_specs=[
-            pl.BlockSpec((tp_, M), lambda p: (p, 0)),
-            pl.BlockSpec((tp_, M), lambda p: (p, 0)),
-            pl.BlockSpec((tp_,), lambda p: (p,)),
-        ],
-        out_specs=pl.BlockSpec((tp_,), lambda p: (p,)),
-        out_shape=jax.ShapeDtypeStruct((Pp,), jnp.float32),
-        interpret=interpret,
-    )(a, b, v)
-    return out[:P]
-
-
-def _masked_counts(sig, a_idx, b_idx, valid, tp: int,
-                   interpret: bool | None):
-    D = sig.shape[0]
-    a_idx = jnp.clip(a_idx, 0, D - 1)
-    b_idx = jnp.clip(b_idx, 0, D - 1)
-    return _masked_counts_rows(sig[a_idx], sig[b_idx], valid, tp,
-                               interpret)
+    valid = jnp.ones(a_idx.shape, jnp.int32)
+    return _counts_rows(*_gather_rows(sig, a_idx, b_idx), valid, tp,
+                        interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "interpret"))
@@ -139,11 +118,9 @@ def masked_pair_counts(
     both live in one local matrix — the cross-shard straggler scoring
     of the sharded dedup path gathers one side from the device's own
     signature shard and the other from the bounded row buffer exchanged
-    inside the all_to_all, then scores the pair here.  Same
-    count-not-estimate contract: the /M division happens on the host so
-    scores stay bit-identical to the host estimator.
+    inside the all_to_all, then scores the pair here.
     """
-    return _masked_counts_rows(sig_a, sig_b, valid, tp, interpret)
+    return _counts_rows(sig_a, sig_b, valid, tp, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "interpret"))
@@ -166,17 +143,11 @@ def masked_indexed_pair_counts(
     point outside the shard — this is the device-resident stage-2
     verify of the sharded dedup path, run under ``shard_map`` over each
     device's own signature shard with a ``psum`` combining the
-    per-shard masked contributions.
-
-    Returning the raw count (instead of the m/M estimate) keeps the
-    kernel output exact: XLA rewrites division by the compile-time
-    constant M into a multiply by its reciprocal, which lands 1 ulp off
-    the host numpy estimator — so the division is done by the consumer
-    (``masked_indexed_pair_estimate`` eagerly, or the host merge in
-    numpy), where it is correctly rounded and drift against the host
-    verifier stays 0.
+    per-shard masked contributions.  The /M division happens on the
+    host merge in numpy (see the module docstring).
     """
-    return _masked_counts(sig, a_idx, b_idx, valid, tp, interpret)
+    return _counts_rows(*_gather_rows(sig, a_idx, b_idx), valid, tp,
+                        interpret)
 
 
 def masked_indexed_pair_estimate(
@@ -187,14 +158,9 @@ def masked_indexed_pair_estimate(
     *,
     tp: int = TP,
     interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Masked fused gather + full-M estimate: counts / M.
-
-    Bit-identical to the numpy estimator when called eagerly (the
-    division executes as a standalone correctly-rounded op).  Inside a
-    larger jit XLA may fold the division into a reciprocal multiply —
-    use ``masked_indexed_pair_counts`` there and divide on the host.
-    """
+) -> np.ndarray:
+    """Masked fused gather + full-M estimate: device counts / M on the
+    host, so the result is bit-identical to the numpy estimator."""
     counts = masked_indexed_pair_counts(
         sig, a_idx, b_idx, valid, tp=tp, interpret=interpret)
-    return counts / jnp.float32(sig.shape[1])
+    return np.asarray(counts) / np.float32(sig.shape[1])

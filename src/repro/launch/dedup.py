@@ -116,7 +116,8 @@ def main(argv=None):
     ap.add_argument("--sharded", action="store_true",
                     help="run the shard_map dedup step")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force host device count (sharded mode)")
+                    help="sharded mode: run on the first N devices "
+                         "(with JAX_PLATFORMS=cpu, N forced host devices)")
     ap.add_argument("--band-groups", type=int, default=1,
                     help="stream the sharded step's verified-edge "
                          "buffers per band-group (G bounded buffers of "
@@ -151,14 +152,21 @@ def main(argv=None):
                          "band index to publish a view over)")
     args = ap.parse_args(argv)
 
-    if args.sharded and args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+    if args.sharded and args.devices and \
+            os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        # The flag only creates CPU host devices; it must precede the
+        # first jax import.  Accelerators are taken as they are.
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+            os.environ.get("XLA_FLAGS"),
+            f"--xla_force_host_platform_device_count={args.devices}"]))
 
     import numpy as np
     import jax
     from repro.core import DedupConfig, DedupSession, RetentionPolicy
     from repro.data import inject_near_duplicates, make_i2b2_like
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     retention = None
     if args.retain_budget != "none" or args.refine_every:
@@ -189,8 +197,16 @@ def main(argv=None):
 
     if args.sharded:
         from repro.core import DistLSHConfig
+        from repro.core.dist_lsh import docs_mesh
 
-        ndev = len(jax.devices())
+        devices = jax.devices()
+        if args.devices:
+            if len(devices) < args.devices:
+                raise SystemExit(
+                    f"--devices {args.devices}: only {len(devices)} "
+                    f"{devices[0].platform} device(s) present")
+            devices = devices[:args.devices]
+        ndev = len(devices)
         dcfg = DistLSHConfig(edge_threshold=args.edge_threshold,
                              edge_capacity=8192,
                              band_groups=args.band_groups,
@@ -205,6 +221,7 @@ def main(argv=None):
         # device).
         sess = DedupSession(replace(cfg, exact_verification=False),
                             backend="sharded", dist_config=dcfg,
+                            mesh=docs_mesh(devices),
                             store_path=args.store_path,
                             retention=retention)
         t0 = time.perf_counter()

@@ -23,9 +23,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.jaxcompat import shard_map_compat
 
 from repro.models.config import ModelConfig, MoECfg
 from repro.models.layers import glu_act
@@ -177,12 +176,12 @@ def moe_ffn_ep(p, cfg: ModelConfig, x):
     body = lambda xt_, r_, wg_, wu_, wd_: _local_moe(
         xt_, r_, wg_, wu_, wd_, cfg=cfg, ep_axis=ep_axis, n_ep=n_ep,
         dp_axes=dp_axes)
-    out, lb, zl, dropf = shard_map_compat(
+    out, lb, zl, dropf = shard_map(
         body, mesh=mesh,
         in_specs=(P(token_axes, None),
                   P(), P(ep_axis), P(ep_axis), P(ep_axis)),
         out_specs=(P(token_axes, None), P(), P(), P()),
-        check_replication=False,
+        check_vma=False,
     )(xt, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     out = out.reshape(B, S, d)

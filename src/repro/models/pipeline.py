@@ -21,9 +21,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.jaxcompat import shard_map_compat
 
 from repro.models import blocks
 from repro.models.config import ModelConfig
@@ -121,11 +120,11 @@ def make_pipelined_loss(cfg: ModelConfig, mesh: Mesh, *, n_micro: int,
         labels = jnp.concatenate(
             [tokens[:, :, 1:], jnp.full_like(tokens[:, :, :1], -1)],
             axis=2)
-        fn = shard_map_compat(
+        fn = shard_map(
             local_fn, mesh=mesh,
             in_specs=(P(), P(), P()),
             out_specs=P(),
-            check_replication=False,
+            check_vma=False,
         )
         return fn(params, tokens, labels)
 

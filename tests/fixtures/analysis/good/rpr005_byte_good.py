@@ -1,10 +1,12 @@
 """Clean fixture: the byte-shingle carry-block tiling (RPR005).
 
-Mirrors ``kernels/byte_shingle.py`` (DESIGN.md §11): grid-varying tile
-dims are min-clamped locals, the FNV-state carry is a revisited rank-1
-output block (same block for every L step, re-initialized at the first
-L tile) whose out_shape rank matches, and the resident tiles stay far
-under the VMEM ceiling.
+Mirrors ``kernels/byte_shingle.py`` (DESIGN.md §11): documents sit on
+the lane axis of a (tlb_, td_) tile, grid-varying tile dims are
+min-clamped locals, and the lengths and the FNV-state carry are 2-D
+(1, td_) blocks (a rank-1 block tiled along a grid axis is not a legal
+TPU block).  The carry is a revisited output block (same block for
+every L step, re-initialized at the first L tile) whose out_shape rank
+matches, and the resident tiles stay far under the VMEM ceiling.
 """
 import jax
 import jax.numpy as jnp
@@ -22,23 +24,23 @@ def _byte_kernel(byte_ref, len_ref, tok_ref, h_ref):
     h_ref[...] = h_ref[...] + len_ref[...].astype(jnp.uint32)
 
 
-def launch(data, lengths, td: int = 8, tlb: int = 256):
-    D, LB = data.shape
+def launch(data, lengths, td: int = 128, tlb: int = 256):
+    LB, D = data.shape
     td_ = min(td, max(1, D))
     tlb_ = min(tlb, max(1, LB))
     return pl.pallas_call(
         _byte_kernel,
         grid=(-(-D // td_), -(-LB // tlb_)),
         in_specs=[
-            pl.BlockSpec((td_, tlb_), lambda d, l: (d, l)),
-            pl.BlockSpec((td_,), lambda d, l: (d,)),
+            pl.BlockSpec((tlb_, td_), lambda d, l: (l, d)),
+            pl.BlockSpec((1, td_), lambda d, l: (0, d)),
         ],
         out_specs=[
-            pl.BlockSpec((td_, tlb_), lambda d, l: (d, l)),
-            pl.BlockSpec((td_,), lambda d, l: (d,)),
+            pl.BlockSpec((tlb_, td_), lambda d, l: (l, d)),
+            pl.BlockSpec((1, td_), lambda d, l: (0, d)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((D, LB), jnp.uint32),
-            jax.ShapeDtypeStruct((D,), jnp.uint32),
+            jax.ShapeDtypeStruct((LB, D), jnp.uint32),
+            jax.ShapeDtypeStruct((1, D), jnp.uint32),
         ],
-    )(data, lengths)
+    )(data, lengths[None, :])

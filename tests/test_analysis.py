@@ -62,6 +62,10 @@ BAD_CASES = [
     ("rpr005_byte_bad.py", "src/repro/kernels/fixture_mod.py", "RPR005",
      {"index-map-arity", "unclamped-dim:TLB", "vmem-budget",
       "out-rank-mismatch"}),
+    # Rank-1 (td_,) blocks tiled along the grid: legal in interpret
+    # mode, refused by the TPU lowering (the pre-chip byte carry).
+    ("rpr005_rank1_bad.py", "src/repro/kernels/fixture_mod.py", "RPR005",
+     {"rank1-block:td_"}),
 ]
 
 
