@@ -37,6 +37,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.candidates import CandidateSource
 from repro.core.unionfind import ThresholdUnionFind
 from repro.core.verify import as_verifier
@@ -159,71 +160,77 @@ class ClusterAccumulator:
             raise ValueError(
                 f"accumulator covers {len(self.uf.parent)} docs, source "
                 f"has {source.num_docs}")
-        uf = self.uf
-        verifier = (self.verifier if verifier is None
-                    else as_verifier(verifier))
-        evaluated = self.evaluated
-        # Snapshot the verifier's lifetime counters so stats report THIS
-        # feed's batches/seconds even when the verifier instance is
-        # reused (e.g. re-clustering at a second threshold).
-        batches0, seconds0 = verifier.n_batches, verifier.seconds
-        stats = ClusterStats()
-        pending: list[tuple[int, int]] = []
-        pending_set: set[tuple[int, int]] = set()
+        with spans.span("merge") as sp:
+            uf = self.uf
+            verifier = (self.verifier if verifier is None
+                        else as_verifier(verifier))
+            evaluated = self.evaluated
+            # Snapshot the verifier's lifetime counters so stats report
+            # THIS feed's batches/seconds even when the verifier instance
+            # is reused (e.g. re-clustering at a second threshold).
+            batches0, seconds0 = verifier.n_batches, verifier.seconds
+            stats = ClusterStats()
+            pending: list[tuple[int, int]] = []
+            pending_set: set[tuple[int, int]] = set()
 
-        def flush():
-            if not pending:
-                return
-            sims = verifier(np.array(pending, dtype=np.int64))
-            for (a, c), sim in zip(pending, sims):
-                sim = float(sim)
-                evaluated[(a, c)] = sim
-                stats.pairs_evaluated += 1
-                if sim > self.edge_threshold:
-                    stats.pairs_above_edge += 1
+            def flush():
+                if not pending:
+                    return
+                sims = verifier(np.array(pending, dtype=np.int64))
+                for (a, c), sim in zip(pending, sims):
+                    sim = float(sim)
+                    evaluated[(a, c)] = sim
+                    stats.pairs_evaluated += 1
+                    if sim > self.edge_threshold:
+                        stats.pairs_above_edge += 1
+                        if self.use_disjoint_sets:
+                            before = uf.n_unions
+                            uf.union(a, c, sim)
+                            if uf.n_unions > before:
+                                stats.unions_done += 1
+                            else:
+                                stats.unions_rejected += 1
+                pending.clear()
+                pending_set.clear()
+
+            for band_runs in source.iter_bands():
+                for members in band_runs.iter_groups():
+                    m = len(members)
+                    stats.pairs_generated += m * (m - 1) // 2
                     if self.use_disjoint_sets:
-                        before = uf.n_unions
-                        uf.union(a, c, sim)
-                        if uf.n_unions > before:
-                            stats.unions_done += 1
-                        else:
-                            stats.unions_rejected += 1
-            pending.clear()
-            pending_set.clear()
-
-        for band_runs in source.iter_bands():
-            for members in band_runs.iter_groups():
-                m = len(members)
-                stats.pairs_generated += m * (m - 1) // 2
-                if self.use_disjoint_sets:
-                    # "replace D with D.find()" — compress to roots.
-                    uniq = np.unique([uf.find(int(d)) for d in members])
-                else:
-                    uniq = np.sort(members)
-                k = len(uniq)
-                if k < 2:
-                    # All members already co-clustered: all excluded.
-                    stats.pairs_excluded += m * (m - 1) // 2
-                    continue
-                # Pairs collapsed by prior clustering are excluded too.
-                stats.pairs_excluded += m * (m - 1) // 2 - k * (k - 1) // 2
-                for ii in range(k):
-                    for jj in range(ii + 1, k):
-                        key = (int(uniq[ii]), int(uniq[jj]))
-                        if key in evaluated or key in pending_set:
-                            stats.pairs_excluded += 1
-                            continue
-                        pending.append(key)
-                        pending_set.add(key)
-                if self.batch == "run" or \
-                        len(pending) >= self.max_batch_pairs:
+                        # "replace D with D.find()" — compress to roots.
+                        uniq = np.unique([uf.find(int(d)) for d in members])
+                    else:
+                        uniq = np.sort(members)
+                    k = len(uniq)
+                    if k < 2:
+                        # All members already co-clustered: all excluded.
+                        stats.pairs_excluded += m * (m - 1) // 2
+                        continue
+                    # Pairs collapsed by prior clustering are excluded too.
+                    stats.pairs_excluded += (m * (m - 1) // 2
+                                             - k * (k - 1) // 2)
+                    for ii in range(k):
+                        for jj in range(ii + 1, k):
+                            key = (int(uniq[ii]), int(uniq[jj]))
+                            if key in evaluated or key in pending_set:
+                                stats.pairs_excluded += 1
+                                continue
+                            pending.append(key)
+                            pending_set.add(key)
+                    if self.batch == "run" or \
+                            len(pending) >= self.max_batch_pairs:
+                        flush()
+                if self.batch == "band":
                     flush()
-            if self.batch == "band":
-                flush()
-        flush()
+            flush()
 
-        stats.verify_batches = verifier.n_batches - batches0
-        stats.verify_seconds = verifier.seconds - seconds0
+            stats.verify_batches = verifier.n_batches - batches0
+            stats.verify_seconds = verifier.seconds - seconds0
+            # The verify calls inside this feed, as a stat of the span
+            # rather than spans of their own: with ``batch="run"`` there
+            # is one call per band run, too many to trace.
+            sp.count(verify_ns=round(stats.verify_seconds * 1e9))
         self.stats.add(stats)
         return stats
 

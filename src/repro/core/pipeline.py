@@ -21,7 +21,6 @@ Execution styles, all thin drivers over the staged engine
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -30,9 +29,16 @@ import jax.numpy as jnp
 from repro.core import lsh
 from repro.core import minhash
 from repro.core import shingle
+from repro.core import spans
 from repro.core.engine import ClusterStats
 from repro.core.unionfind import ThresholdUnionFind
 from repro.core.verify import ExactJaccardVerifier, SignatureVerifier
+
+
+def _h2d(*host_arrays: np.ndarray) -> dict:
+    """Span count of one device-ingest call: the bytes its host arrays
+    copy to the device."""
+    return {"h2d_bytes": sum(a.nbytes for a in host_arrays)}
 
 
 @dataclass(frozen=True)
@@ -109,10 +115,10 @@ class DedupPipeline:
         self.seeds = minhash.default_seeds(self.config.num_hashes)
         self._seeds_dev = None
         self._seeds_src = None
-        # Per-stage wall times of the LAST compute call (cumulative
-        # ``_s`` keys); chunked ingest (``core.session``) sums these
-        # across chunks, so the kops and fused paths time their device
-        # work (block-until-transfer) the same way the numpy path does.
+        # Per-stage host seconds of the LAST call of each stage
+        # (``tokenize_s``, ``signature_s``, ``bands_s``), taken by the
+        # stage's ``spans.span``; device stages end on the transfer
+        # back to numpy, so every backend times its device work alike.
         self.stage_timings: dict[str, float] = {}
 
     def device_seeds(self) -> jnp.ndarray:
@@ -130,50 +136,55 @@ class DedupPipeline:
     # -- stages ------------------------------------------------------------
 
     def tokenize(self, texts: list[str]) -> list[list[str]]:
-        return [shingle.tokenize(t) for t in texts]
+        with spans.span("tokenize") as s:
+            toks = [shingle.tokenize(t) for t in texts]
+        self.stage_timings["tokenize_s"] = s.seconds
+        return toks
 
     def compute_signatures(self, token_lists: list[list[str]],
                            pad_len: int | None = None) -> np.ndarray:
-        t0 = time.perf_counter()
-        packed = shingle.pack_documents(token_lists, pad_len)
-        if self.config.use_pallas or self.config.fused_ingest:
-            from repro.kernels import ops as kops
+        with spans.span("pack") as pack:
+            packed = shingle.pack_documents(token_lists, pad_len)
+        with spans.span("device_ingest", **_h2d(packed.tokens,
+                                                packed.lengths)) as dev:
+            if self.config.use_pallas or self.config.fused_ingest:
+                from repro.kernels import ops as kops
 
-            if self.config.fused_ingest:
-                sig, _, _ = kops.fused_ingest(
-                    jnp.asarray(packed.tokens),
-                    jnp.asarray(packed.lengths),
-                    self.device_seeds(),
-                    n=self.config.ngram,
-                    r=self.config.rows_per_band,
-                )
+                if self.config.fused_ingest:
+                    sig, _, _ = kops.fused_ingest(
+                        jnp.asarray(packed.tokens),
+                        jnp.asarray(packed.lengths),
+                        self.device_seeds(),
+                        n=self.config.ngram,
+                        r=self.config.rows_per_band,
+                    )
+                else:
+                    ng, valid = kops.ngram_hashes(
+                        jnp.asarray(packed.tokens),
+                        jnp.asarray(packed.lengths),
+                        n=self.config.ngram,
+                    )
+                    sig = kops.minhash_signatures(ng, valid,
+                                                  self.device_seeds())
             else:
-                ng, valid = kops.ngram_hashes(
+                ng, valid = shingle.ngram_hashes(
                     jnp.asarray(packed.tokens),
                     jnp.asarray(packed.lengths),
                     n=self.config.ngram,
                 )
-                sig = kops.minhash_signatures(ng, valid,
-                                              self.device_seeds())
-        else:
-            ng, valid = shingle.ngram_hashes(
-                jnp.asarray(packed.tokens),
-                jnp.asarray(packed.lengths),
-                n=self.config.ngram,
-            )
-            sig = minhash.signatures(ng, valid, self.device_seeds())
-        # np.asarray blocks on the device work, so the kops/fused paths
-        # record the same wall semantics as the numpy path.
-        sig = np.asarray(sig)
-        self.stage_timings["signature_s"] = time.perf_counter() - t0
+                sig = minhash.signatures(ng, valid, self.device_seeds())
+            # np.asarray blocks on the device work, so the kops/fused
+            # paths record the same wall semantics as the numpy path.
+            sig = np.asarray(sig)
+        self.stage_timings["signature_s"] = pack.seconds + dev.seconds
         return sig
 
     def compute_bands(self, sig: np.ndarray) -> np.ndarray:
-        t0 = time.perf_counter()
-        bands = np.asarray(
-            lsh.band_values(jnp.asarray(sig), self.config.rows_per_band)
-        )
-        self.stage_timings["bands_s"] = time.perf_counter() - t0
+        with spans.span("device_ingest", h2d_bytes=sig.nbytes) as dev:
+            bands = np.asarray(
+                lsh.band_values(jnp.asarray(sig), self.config.rows_per_band)
+            )
+        self.stage_timings["bands_s"] = dev.seconds
         return bands
 
     def compute_arrays(
@@ -203,17 +214,19 @@ class DedupPipeline:
             return sig, self.compute_bands(sig)
         from repro.kernels import ops as kops
 
-        t0 = time.perf_counter()
-        packed = shingle.pack_documents(token_lists, pad_len)
-        sig, bands, _ = kops.fused_ingest(
-            jnp.asarray(packed.tokens),
-            jnp.asarray(packed.lengths),
-            self.device_seeds(),
-            n=self.config.ngram,
-            r=self.config.rows_per_band,
-        )
-        sig, bands = np.asarray(sig), np.asarray(bands)
-        self.stage_timings["signature_s"] = time.perf_counter() - t0
+        with spans.span("pack") as pack:
+            packed = shingle.pack_documents(token_lists, pad_len)
+        with spans.span("device_ingest", **_h2d(packed.tokens,
+                                                packed.lengths)) as dev:
+            sig, bands, _ = kops.fused_ingest(
+                jnp.asarray(packed.tokens),
+                jnp.asarray(packed.lengths),
+                self.device_seeds(),
+                n=self.config.ngram,
+                r=self.config.rows_per_band,
+            )
+            sig, bands = np.asarray(sig), np.asarray(bands)
+        self.stage_timings["signature_s"] = pack.seconds + dev.seconds
         self.stage_timings["bands_s"] = 0.0  # fused into the one pass
         return sig, bands
 
@@ -235,17 +248,19 @@ class DedupPipeline:
         """
         from repro.kernels import ops as kops
 
-        t0 = time.perf_counter()
-        packed = shingle.pack_bytes(docs, pad_len)
-        sig, bands, _ = kops.bytes_to_bands(
-            jnp.asarray(packed.data),
-            jnp.asarray(packed.lengths),
-            self.device_seeds(),
-            n=self.config.ngram,
-            r=self.config.rows_per_band,
-        )
-        sig, bands = np.asarray(sig), np.asarray(bands)
-        self.stage_timings["signature_s"] = time.perf_counter() - t0
+        with spans.span("pack") as pack:
+            packed = shingle.pack_bytes(docs, pad_len)
+        with spans.span("device_ingest", **_h2d(packed.data,
+                                                packed.lengths)) as dev:
+            sig, bands, _ = kops.bytes_to_bands(
+                jnp.asarray(packed.data),
+                jnp.asarray(packed.lengths),
+                self.device_seeds(),
+                n=self.config.ngram,
+                r=self.config.rows_per_band,
+            )
+            sig, bands = np.asarray(sig), np.asarray(bands)
+        self.stage_timings["signature_s"] = pack.seconds + dev.seconds
         self.stage_timings["bands_s"] = 0.0  # fused into the one pass
         return sig, bands
 
@@ -300,23 +315,21 @@ class DedupPipeline:
                 max((len(t.encode("utf-8")) for t in texts), default=0) + 1)
             sig, bands = self.compute_arrays_bytes(texts, pad_len)
         else:
-            t0 = time.perf_counter()
             token_lists = self.tokenize(texts)
-            timings["tokenize_s"] = time.perf_counter() - t0
-
+            timings["tokenize_s"] = self.stage_timings["tokenize_s"]
             sig, bands = self.compute_arrays(token_lists)
         timings["signatures_s"] = self.stage_timings["signature_s"]
         timings["bands_s"] = self.stage_timings["bands_s"]
 
-        t0 = time.perf_counter()
-        verifier = self.make_verifier(token_lists, sig)
-        timings["verifier_build_s"] = time.perf_counter() - t0
+        with spans.span("verifier_build") as s:
+            verifier = self.make_verifier(token_lists, sig)
+        timings["verifier_build_s"] = s.seconds
 
-        t0 = time.perf_counter()
-        sess = DedupSession(cfg, backend="host", verifier=verifier)
-        snap = sess._merge_precomputed(token_lists, sig, bands)
-        uf, stats, pairs = sess.uf, snap.stats, snap.pairs
-        timings["cluster_s"] = time.perf_counter() - t0
+        with spans.span("cluster") as s:
+            sess = DedupSession(cfg, backend="host", verifier=verifier)
+            snap = sess._merge_precomputed(token_lists, sig, bands)
+            uf, stats, pairs = sess.uf, snap.stats, snap.pairs
+        timings["cluster_s"] = s.seconds
         timings["verify_s"] = stats.verify_seconds
 
         labels = snap.labels

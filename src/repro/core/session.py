@@ -51,7 +51,7 @@ from typing import Iterable, Iterator
 import numpy as np
 import jax.numpy as jnp
 
-from repro.core import lsh, minhash, shingle
+from repro.core import lsh, minhash, shingle, spans
 from repro.core.bandstore import SqliteBandStore
 from repro.core.candidates import BandMatrixSource, ShardedEdgeSource
 from repro.core.engine import (
@@ -598,31 +598,35 @@ class DedupSession:
         return sig
 
     def snapshot(self) -> ClusterSnapshot:
-        v = self._verifier
-        retained = getattr(v, "n_live_rows", None)
-        labels = self.uf.components()[: self.n_docs]
-        labels.setflags(write=False)
-        return ClusterSnapshot(
-            n_docs=self.n_docs,
-            labels=labels,
-            stats=replace(self.acc.stats),
-            pairs=self.acc.pairs,
-            _uf=self.uf,
-            overflow=self.overflow,
-            retried=self.retried,
-            device_scored=getattr(v, "n_passthrough", 0),
-            host_rescored=getattr(v, "n_rescored", 0),
-            row_overflow=self.row_overflow,
-            retained_rows=(retained if retained is not None
-                           else self.n_docs),
-            evicted=(self.retention.n_evicted
-                     if self.retention is not None else 0),
-            filter_only_hits=self.band_index.filter_only_hits,
-            refine_merges=self.refine_merges,
-            representatives=(np.array(self.retention.representatives(),
-                                      dtype=np.int64)
-                             if self.retention is not None else None),
-        )
+        with spans.span("snapshot") as sp:
+            v = self._verifier
+            retained = getattr(v, "n_live_rows", None)
+            labels = self.uf.components()[: self.n_docs]
+            labels.setflags(write=False)
+            pairs = self.acc.pairs
+            snap = ClusterSnapshot(
+                n_docs=self.n_docs,
+                labels=labels,
+                stats=replace(self.acc.stats),
+                pairs=pairs,
+                _uf=self.uf,
+                overflow=self.overflow,
+                retried=self.retried,
+                device_scored=getattr(v, "n_passthrough", 0),
+                host_rescored=getattr(v, "n_rescored", 0),
+                row_overflow=self.row_overflow,
+                retained_rows=(retained if retained is not None
+                               else self.n_docs),
+                evicted=(self.retention.n_evicted
+                         if self.retention is not None else 0),
+                filter_only_hits=self.band_index.filter_only_hits,
+                refine_merges=self.refine_merges,
+                representatives=(np.array(self.retention.representatives(),
+                                          dtype=np.int64)
+                                 if self.retention is not None else None),
+            )
+            sp.count(pairs=len(pairs))
+        return snap
 
     # -- read path (SessionView publication, DESIGN.md §9) -------------------
 
@@ -724,19 +728,22 @@ class DedupSession:
     def ingest(self, texts: Iterable[str]) -> ClusterSnapshot:
         """Cluster one chunk of documents; returns a cumulative snapshot."""
         self._check_live()
-        pending = self._impl.dispatch(list(texts))
-        self._impl.merge(pending)
-        self._post_merge()
-        return self.snapshot()
+        with spans.span("ingest"):
+            pending = self._impl.dispatch(list(texts))
+            self._impl.merge(pending)
+            self._post_merge()
+            return self.snapshot()
 
     def ingest_tokens(self,
                       token_lists: list[list[str]]) -> ClusterSnapshot:
         """``ingest`` over pre-tokenized documents."""
         self._check_live()
-        pending = self._impl.dispatch(list(token_lists), tokenized=True)
-        self._impl.merge(pending)
-        self._post_merge()
-        return self.snapshot()
+        with spans.span("ingest"):
+            pending = self._impl.dispatch(list(token_lists),
+                                          tokenized=True)
+            self._impl.merge(pending)
+            self._post_merge()
+            return self.snapshot()
 
     def ingest_stream(
         self, chunks: Iterable[list], *, tokenized: bool = False,
@@ -916,25 +923,26 @@ class DedupSession:
         """
         if self._external_verifier:
             return
-        sig = np.asarray(sig)
-        cfg = self.config
-        if self._verifier is None:
-            gap = self.n_merged  # ids below the first chunk's base
-            if self._wants_exact():
-                self._verifier = ExactJaccardVerifier.from_token_lists(
-                    [[]] * gap + list(token_lists), cfg.ngram)
-                return
-            full = sig if gap == 0 else np.concatenate(
-                [np.zeros((gap, sig.shape[1]), dtype=sig.dtype), sig])
-            cls = (DeviceScoredEdgeVerifier
-                   if self.backend == "sharded"
-                   and self._impl.stage2 == "device"
-                   else SignatureVerifier)
-            self._verifier = cls(full, backend=cfg.resolved_backend())
-        elif self._wants_exact():
-            self._verifier.extend_token_lists(token_lists)
-        else:
-            self._verifier.extend_signatures(sig)
+        with spans.span("retain"):
+            sig = np.asarray(sig)
+            cfg = self.config
+            if self._verifier is None:
+                gap = self.n_merged  # ids below the first chunk's base
+                if self._wants_exact():
+                    self._verifier = ExactJaccardVerifier.from_token_lists(
+                        [[]] * gap + list(token_lists), cfg.ngram)
+                    return
+                full = sig if gap == 0 else np.concatenate(
+                    [np.zeros((gap, sig.shape[1]), dtype=sig.dtype), sig])
+                cls = (DeviceScoredEdgeVerifier
+                       if self.backend == "sharded"
+                       and self._impl.stage2 == "device"
+                       else SignatureVerifier)
+                self._verifier = cls(full, backend=cfg.resolved_backend())
+            elif self._wants_exact():
+                self._verifier.extend_token_lists(token_lists)
+            else:
+                self._verifier.extend_signatures(sig)
 
     def _wants_exact(self) -> bool:
         return self.backend == "host" and self.config.exact_verification
@@ -962,7 +970,8 @@ class DedupSession:
 
     def _feed_cross_step(self, bands: np.ndarray, base: int) -> None:
         """Cross-step candidates: chunk bands vs the retained index."""
-        edges = self.band_index.match_then_insert(bands, base)
+        with spans.span("band_index"):
+            edges = self.band_index.match_then_insert(bands, base)
         if len(edges):
             self.acc.feed(
                 ShardedEdgeSource(edges, num_docs=self.n_docs),

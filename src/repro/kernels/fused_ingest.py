@@ -182,6 +182,9 @@ def fused_ingest(
             jax.ShapeDtypeStruct((2, Dp, Mp // r), jnp.uint32),
         ],
         interpret=interpret,
+        # A stable device-op name for operators reading a trace; the
+        # ``jit_fused_ingest`` program around it keeps the jit's name.
+        name="fused_ingest",
     )(tok, tok, ln, sd)
     sig = sig.reshape(Dp, n_m, r, bt).transpose(0, 1, 3, 2).reshape(Dp, Mp)
     bands = bands.transpose(1, 2, 0)             # (Dp, Mp // r, 2)

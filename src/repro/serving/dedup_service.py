@@ -122,8 +122,7 @@ class DedupQueryService:
             # Byte sessions tokenize on device (no-stem); the host
             # tokenizer below would stem and miss the ingested rows.
             return self.query_bytes(texts)
-        return self.query_tokens([self.pipe.tokenize([t])[0]
-                                  for t in texts])
+        return self.query_tokens(self.pipe.tokenize(texts))
 
     def query_bytes(self, texts: list[str | bytes]) -> list[QueryResult]:
         """``query`` straight from UTF-8 bytes — the zero-copy read path.
@@ -217,9 +216,8 @@ class DedupQueryService:
         # token-path signatures over those tokens are bit-identical to
         # the bytes_to_bands chain, so microbatched results agree with
         # query_bytes exactly.
-        toks = (shingle.tokenize(text, do_stem=False)
-                if self.session.config.byte_ingest
-                else self.pipe.tokenize([text])[0])
+        toks = shingle.tokenize(
+            text, do_stem=not self.session.config.byte_ingest)
         self.queue.append(QueryRequest(
             self._rid, toks, enqueued_at=time.perf_counter()))
         return self._rid
