@@ -85,7 +85,12 @@ from repro.core.candidates import (
     StoreBandSource,
     candidate_pairs,
 )
-from repro.core.engine import ClusterAccumulator, ClusterStats, cluster_source
+from repro.core.engine import (
+    ClusterAccumulator,
+    ClusterStats,
+    PairList,
+    cluster_source,
+)
 from repro.core.verify import (
     BatchVerifier,
     CallbackVerifier,
@@ -130,6 +135,7 @@ __all__ = [
     "candidate_pairs",
     "ClusterAccumulator",
     "ClusterStats",
+    "PairList",
     "cluster_source",
     "BatchVerifier",
     "CallbackVerifier",

@@ -489,7 +489,7 @@ class ShardedClusterResult:
 
     uf: "ThresholdUnionFind"
     stats: "ClusterStats"
-    pairs: list  # evaluated (a, b, sim) with full-signature sims
+    pairs: "PairList"  # evaluated (a, b, sim) with full-signature sims
     num_edges: int          # stage-1 survivors fed into the engine
     overflow: int           # device bucket/edge-buffer overflow count
     retried: bool           # True when the overflow fallback pass ran

@@ -33,7 +33,9 @@ of the previous three copies is gone.
 """
 from __future__ import annotations
 
+from collections.abc import Sequence
 from dataclasses import dataclass
+from itertools import chain, islice
 
 import numpy as np
 
@@ -71,6 +73,74 @@ class ClusterStats:
         ):
             setattr(self, f, getattr(self, f) + getattr(other, f))
         return self
+
+
+def _sort_keys(ab: np.ndarray) -> np.ndarray:
+    """One int64 per (a, b) that sorts as the tuple does (ids < 2**31)."""
+    return (ab[:, 0] << 32) | ab[:, 1]
+
+
+class PairList(Sequence):
+    """Verified ``(a, b, sim)`` triples sorted by ``(a, b)``: an
+    immutable sequence over two columns, which it makes read-only.
+
+    ``ab`` is (P, 2) int64 and ``sim`` (P,) float64, so each sim is the
+    Python float the verified-sim cache holds, bit for bit.  Reading
+    yields ``(int, int, float)`` tuples built on demand; slicing gives a
+    ``PairList`` view; ``==`` compares with another ``PairList``, a list
+    or a tuple as the list of those tuples would.
+    """
+
+    __slots__ = ("ab", "sim")
+    __hash__ = None
+
+    def __init__(self, ab: np.ndarray, sim: np.ndarray):
+        ab.setflags(write=False)
+        sim.setflags(write=False)
+        self.ab = ab
+        self.sim = sim
+
+    @classmethod
+    def empty(cls) -> "PairList":
+        return cls(np.zeros((0, 2), np.int64), np.zeros(0, np.float64))
+
+    def merged(self, ab: np.ndarray, sim: np.ndarray) -> "PairList":
+        """A new list holding these pairs and ``(ab, sim)`` (keys not
+        already present, any order).  ``self`` is left as it was."""
+        if not len(ab):
+            return self
+        if ab.min() < 0 or ab.max() >= 1 << 31:
+            raise ValueError("pair ids must lie in [0, 2**31)")
+        ab = np.concatenate([self.ab, ab])
+        # Stable sort of one sorted run and an unsorted tail: timsort
+        # merges the two runs, so the old pairs cost O(P), not O(P log P).
+        order = np.argsort(_sort_keys(ab), kind="stable")
+        return PairList(ab[order], np.concatenate([self.sim, sim])[order])
+
+    def __len__(self) -> int:
+        return len(self.sim)
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return PairList(self.ab[i], self.sim[i])
+        a, b = self.ab[i].tolist()
+        return a, b, self.sim[i].item()
+
+    def __iter__(self):
+        return zip(self.ab[:, 0].tolist(), self.ab[:, 1].tolist(),
+                   self.sim.tolist())
+
+    def __eq__(self, other):
+        if isinstance(other, PairList):
+            return (np.array_equal(self.ab, other.ab)
+                    and np.array_equal(self.sim, other.sim))
+        if isinstance(other, (list, tuple)):
+            return len(other) == len(self) and type(other)(self) == other
+        return NotImplemented
+
+    def __repr__(self) -> str:
+        head = ", ".join(map(repr, self[:3]))
+        return f"PairList([{head}{', ...' if len(self) > 3 else ''}])"
 
 
 class ClusterAccumulator:
@@ -134,11 +204,32 @@ class ClusterAccumulator:
         self.max_batch_pairs = int(max_batch_pairs)
         self.stats = ClusterStats()
         self.evaluated: dict[tuple[int, int], float] = {}
+        # ``evaluated``'s first len(_sorted) entries, sorted.  Keys are
+        # only ever added (here and by ``merge_cluster_rounds``' shared
+        # sim cache), so its insertion-order tail is what is new.
+        self._sorted = PairList.empty()
 
     @property
-    def pairs(self) -> list[tuple[int, int, float]]:
-        """Every evaluated (a, b, sim), sorted, across all feeds."""
-        return [(a, b, s) for (a, b), s in sorted(self.evaluated.items())]
+    def n_sorted(self) -> int:
+        """Evaluated pairs already folded into the sorted ``pairs``."""
+        return len(self._sorted)
+
+    @property
+    def pairs(self) -> PairList:
+        """Every evaluated (a, b, sim), sorted, across all feeds.
+
+        Folds in only the pairs evaluated since the last read; a list
+        returned earlier keeps its contents."""
+        n = len(self.evaluated) - len(self._sorted)
+        if n:
+            start = len(self._sorted)
+            ab = np.fromiter(
+                chain.from_iterable(islice(self.evaluated, start, None)),
+                dtype=np.int64, count=2 * n).reshape(n, 2)
+            sim = np.fromiter(islice(self.evaluated.values(), start, None),
+                              dtype=np.float64, count=n)
+            self._sorted = self._sorted.merged(ab, sim)
+        return self._sorted
 
     @property
     def num_docs(self) -> int:
@@ -245,13 +336,14 @@ def cluster_source(
     batch: str = "run",
     max_batch_pairs: int = 8192,
     uf: ThresholdUnionFind | None = None,
-) -> tuple[ThresholdUnionFind, ClusterStats, list[tuple[int, int, float]]]:
+) -> tuple[ThresholdUnionFind, ClusterStats, PairList]:
     """Run the staged engine over a candidate source.
 
     ``verifier`` is a ``verify.BatchVerifier`` or a scalar
     ``fn(a, b) -> float`` (wrapped via ``verify.as_verifier``).
-    Returns (union-find, stats, evaluated_pairs [(a, b, sim), ...]) —
-    the same contract the historical ``cluster_bands`` had.
+    Returns (union-find, stats, evaluated pairs) — the same contract
+    the historical ``cluster_bands`` had, the pairs as a sorted
+    ``PairList`` of (a, b, sim).
 
     With ``use_disjoint_sets=False`` every candidate pair is evaluated
     (the paper's non-clustered baseline behind Table 5's "6388 pairs").

@@ -30,7 +30,7 @@ from repro.core import lsh
 from repro.core import minhash
 from repro.core import shingle
 from repro.core import spans
-from repro.core.engine import ClusterStats
+from repro.core.engine import ClusterStats, PairList
 from repro.core.unionfind import ThresholdUnionFind
 from repro.core.verify import ExactJaccardVerifier, SignatureVerifier
 
@@ -91,7 +91,7 @@ class DedupConfig:
 class DedupResult:
     labels: np.ndarray  # (D,) cluster root per doc
     keep_mask: np.ndarray  # (D,) bool — True for cluster representatives
-    pairs: list  # evaluated (a, b, sim)
+    pairs: PairList  # evaluated (a, b, sim), sorted
     stats: ClusterStats
     uf: ThresholdUnionFind
     signatures: np.ndarray  # (D, M) uint32

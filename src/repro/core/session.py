@@ -57,6 +57,7 @@ from repro.core.candidates import BandMatrixSource, ShardedEdgeSource
 from repro.core.engine import (
     ClusterAccumulator,
     ClusterStats,
+    PairList,
     merge_cluster_rounds,
 )
 from repro.core.pipeline import DedupConfig
@@ -271,9 +272,10 @@ class BandIndex:
 class ClusterSnapshot:
     """Cluster state after an ``ingest`` call — a pure VALUE object.
 
-    Every public field is a copy (``labels`` is frozen read-only,
-    ``stats`` is a counter copy, ``pairs`` is a fresh list built from
-    the verified-sim cache) or an immutable scalar: holding a snapshot
+    Every public field is a copy or a frozen value (``labels`` is frozen
+    read-only, ``stats`` is a counter copy, ``pairs`` is a ``PairList``
+    over frozen sorted columns that later ingests extend into new
+    arrays) or an immutable scalar: holding a snapshot
     never pins live session state, and later ingests cannot change what
     a snapshot already reported.  The LIVE handles moved off the public
     surface in PR 7 — ``DedupSession.uf`` is the live union-find, and
@@ -285,7 +287,7 @@ class ClusterSnapshot:
     n_docs: int                 # docs ingested so far (id upper bound)
     labels: np.ndarray          # (n_docs,) cluster root per doc (frozen)
     stats: ClusterStats         # cumulative engine counters (a copy)
-    pairs: list                 # every evaluated (a, b, sim) so far (a copy)
+    pairs: PairList             # every evaluated (a, b, sim) so far (frozen)
     overflow: int = 0           # sharded: device buffer overflow so far
     retried: int = 0            # sharded: overflow fallback passes run
     device_scored: int = 0      # sharded stage2=device: pass-throughs
@@ -603,6 +605,7 @@ class DedupSession:
             retained = getattr(v, "n_live_rows", None)
             labels = self.uf.components()[: self.n_docs]
             labels.setflags(write=False)
+            n_sorted = self.acc.n_sorted
             pairs = self.acc.pairs
             snap = ClusterSnapshot(
                 n_docs=self.n_docs,
@@ -625,7 +628,7 @@ class DedupSession:
                                           dtype=np.int64)
                                  if self.retention is not None else None),
             )
-            sp.count(pairs=len(pairs))
+            sp.count(pairs=len(pairs), sorted=len(pairs) - n_sorted)
         return snap
 
     # -- read path (SessionView publication, DESIGN.md §9) -------------------

@@ -88,6 +88,51 @@ def test_host_ingest_stream_equals_sequential_ingest():
     assert seq_snaps[-1].pairs == stream_snaps[-1].pairs
 
 
+def test_snapshot_pairs_stay_frozen_across_later_ingests():
+    notes = _corpus(60, 40, seed=7)
+    sess = DedupSession(DedupConfig(exact_verification=False),
+                        backend="host")
+    chunks = _chunks(notes, 3)
+    snap1 = sess.ingest(chunks[0])
+    held = list(snap1.pairs)
+    for c in chunks[1:]:
+        last = sess.ingest(c)
+    assert len(last.pairs) > len(held) > 0
+    assert len(snap1.pairs) == len(held) and snap1.pairs == held
+    for col in (snap1.pairs.ab, snap1.pairs.sim):
+        assert not col.flags.writeable
+
+
+@pytest.mark.parametrize("refine_every", [None, 2])
+def test_snapshots_sort_each_pair_once(tmp_path, refine_every):
+    """The ``sorted`` counts of the ``dedup.snapshot`` spans add up to
+    the pairs of the last snapshot: no pair is sorted twice, whether
+    ``feed`` or a refine round wrote it."""
+    import jax
+
+    from repro.core import RetentionPolicy, spans
+
+    retention = (None if refine_every is None else
+                 RetentionPolicy.preset("none", refine_every=refine_every))
+    sess = DedupSession(DedupConfig(exact_verification=False),
+                        backend="host", retention=retention)
+    spans.take()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        snaps = [sess.ingest(c) for c in _chunks(_corpus(48, 32, seed=9), 4)]
+        snaps.append(sess.refine())
+    finally:
+        jax.profiler.stop_trace()
+    kept = [s[4] for s in spans.take() if s[0] == "dedup.snapshot"]
+    # An auto-refine inside ``ingest`` takes a snapshot of its own.
+    assert len(kept) == len(snaps) + (0 if refine_every is None else 2)
+    sizes = [k["pairs"] for k in kept]
+    assert sizes[-1] == len(snaps[-1].pairs)
+    assert [k["sorted"] for k in kept] == [
+        b - a for a, b in zip([0] + sizes, sizes)]
+    assert sum(k["sorted"] for k in kept) == len(snaps[-1].pairs) > 0
+
+
 @pytest.mark.parametrize("exact", [True, False])
 def test_host_session_doc_id_base_resumed_ingest(exact):
     """Regression: a doc_id_base > 0 session must verify through global
