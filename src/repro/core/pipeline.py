@@ -57,6 +57,11 @@ class DedupConfig:
     byte_ingest: bool = False  # device bytes->bands (no-stem, zero-copy)
     verify_backend: str = "auto"  # estimate mode: numpy | jnp | pallas
     verify_batch: str = "run"  # engine batch granularity: run | band
+    # Rows of the device signature store of the jnp/pallas verify
+    # backends (``verify.SignatureStore``): a deployment sizes it for
+    # its corpus so the store never changes shape; 0 grows it by
+    # doubling.
+    sig_store_capacity: int = 0
     seed: int = 0x5EED
     # Band-store tier (core.bandstore, DESIGN.md §12): "memory" keeps
     # the historical in-RAM layout; "sqlite" puts band rows + signature
@@ -233,7 +238,9 @@ class DedupPipeline:
     def compute_arrays_bytes(
         self, docs: list[str | bytes],
         pad_len: int | None = None,
-    ) -> tuple[np.ndarray, np.ndarray]:
+        *,
+        keep_device: bool = False,
+    ) -> tuple:
         """One chunk's (signatures, band values) straight from UTF-8 bytes.
 
         The ``byte_ingest`` hot path: tokenization never happens on the
@@ -245,6 +252,8 @@ class DedupPipeline:
 
         ``pad_len`` buckets the byte-matrix width (must exceed the
         longest document's byte length; see ``shingle.pack_bytes``).
+        With ``keep_device`` the device array of the signatures comes
+        back third, for a verifier that keeps its rows on the device.
         """
         from repro.kernels import ops as kops
 
@@ -252,17 +261,17 @@ class DedupPipeline:
             packed = shingle.pack_bytes(docs, pad_len)
         with spans.span("device_ingest", **_h2d(packed.data,
                                                 packed.lengths)) as dev:
-            sig, bands, _ = kops.bytes_to_bands(
+            sig_dev, bands, _ = kops.bytes_to_bands(
                 jnp.asarray(packed.data),
                 jnp.asarray(packed.lengths),
                 self.device_seeds(),
                 n=self.config.ngram,
                 r=self.config.rows_per_band,
             )
-            sig, bands = np.asarray(sig), np.asarray(bands)
+            sig, bands = np.asarray(sig_dev), np.asarray(bands)
         self.stage_timings["signature_s"] = pack.seconds + dev.seconds
         self.stage_timings["bands_s"] = 0.0  # fused into the one pass
-        return sig, bands
+        return (sig, bands, sig_dev) if keep_device else (sig, bands)
 
     def ingest_arrays(
         self, token_lists: list[list[str]]
@@ -290,7 +299,8 @@ class DedupPipeline:
         if cfg.exact_verification:
             return ExactJaccardVerifier.from_token_lists(
                 token_lists, cfg.ngram)
-        return SignatureVerifier(sig, backend=cfg.resolved_backend())
+        return SignatureVerifier(sig, backend=cfg.resolved_backend(),
+                                 capacity=cfg.sig_store_capacity)
 
     # -- end to end ----------------------------------------------------------
 

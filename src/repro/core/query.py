@@ -297,19 +297,11 @@ class ViewVerifier:
         retained = self._device_retained()
         n_ret = retained.shape[0]
         stack = jnp.concatenate([retained, jnp.asarray(q_sigs)], axis=0)
-        a_np = view.slot_index(cand_ids)
-        b_np = n_ret + q_idx
-        # Same power-of-two index bucketing as SignatureVerifier: a
-        # stable, bounded set of jit shapes across microbatch sizes.
-        p = len(cand_ids)
-        bucket = 256
-        while bucket < p:
-            bucket *= 2
-        a_dev = jnp.asarray(np.pad(a_np, (0, bucket - p)))
-        b_dev = jnp.asarray(np.pad(b_np, (0, bucket - p)))
         from repro.core.verify import device_estimate
 
-        return device_estimate(self.backend, stack, a_dev, b_dev)[:p]
+        # device_estimate pads the indices to its pair buckets.
+        return device_estimate(self.backend, stack,
+                               view.slot_index(cand_ids), n_ret + q_idx)
 
 
 class ExactViewVerifier:

@@ -141,7 +141,10 @@ class BandIndex:
     def __init__(self, num_bands: int, *, key_budget: int | None = None,
                  bloom_bits: int = 1 << 17, bloom_hashes: int = 4,
                  track_entries: bool = False):
-        self._maps: list[dict[tuple[int, int], list[int]]] = [
+        # A bucket of one doc holds the bare id, a larger one a list:
+        # with a list per key, every full garbage collection walked
+        # one list per (band, note), a pause that grew with the index.
+        self._maps: list[dict[tuple[int, int], int | list[int]]] = [
             {} for _ in range(num_bands)]
         self._key_budget = key_budget
         self._bloom_bits = int(bloom_bits)
@@ -177,23 +180,25 @@ class BandIndex:
             for i in range(len(col)):
                 key = (int(col[i, 0]), int(col[i, 1]))
                 new_id = doc_id_base + i
-                olds = m.get(key)
+                # A hit is popped and put back: the budget sweep pops
+                # from the FRONT of the dict, so a hit must move its
+                # key to the end or a HOT key (a duplicate recurring
+                # every chunk) would be compacted by insertion age and
+                # break the within-window parity invariant.
+                olds = m.pop(key, None)
                 if olds is not None:
+                    if not isinstance(olds, list):
+                        olds = [olds]
                     edges.extend((old, new_id) for old in olds
                                  if old < doc_id_base)
                     olds.append(new_id)
-                    # Refresh recency: the budget sweep pops from the
-                    # FRONT of the dict, so a hit must move its key to
-                    # the end or a HOT key (a duplicate recurring every
-                    # chunk) would be compacted by insertion age and
-                    # break the within-window parity invariant.
-                    m[key] = m.pop(key)
+                    m[key] = olds
                 else:
                     if flt is not None and key in flt:
                         # Seen before, partner compacted away: the pair
                         # can no longer be exactly re-verified.
                         self.filter_only_hits += 1
-                    m[key] = [new_id]
+                    m[key] = new_id
                 if self._entries is not None:
                     self._entries.setdefault(new_id, []).append((j, key))
             if self._key_budget is not None:
@@ -226,6 +231,12 @@ class BandIndex:
                 olds = self._maps[j].get(key)
                 if olds is None:
                     continue               # key already compacted
+                if not isinstance(olds, list):
+                    if olds == d:          # the doc's own bucket of one
+                        r = int(root_of(d))
+                        self._maps[j][key] = r
+                        self._entries.setdefault(r, []).append((j, key))
+                    continue
                 try:
                     olds.remove(d)
                 except ValueError:
@@ -244,7 +255,8 @@ class BandIndex:
         or ``evict`` (DESIGN.md §9).  Pure read — recency (the LRU
         compaction order) is NOT refreshed.
         """
-        return tuple({k: tuple(v) for k, v in m.items()}
+        return tuple({k: tuple(v) if isinstance(v, list) else (v,)
+                      for k, v in m.items()}
                      for m in self._maps)
 
     def export_filters(self) -> tuple:
@@ -257,8 +269,8 @@ class BandIndex:
         """Memory/recall accounting for reports and the soak benchmark."""
         return {
             "n_keys": sum(len(m) for m in self._maps),
-            "n_entries": sum(len(v) for m in self._maps
-                             for v in m.values()),
+            "n_entries": sum(len(v) if isinstance(v, list) else 1
+                             for m in self._maps for v in m.values()),
             "n_docs_tracked": (len(self._entries)
                                if self._entries is not None else 0),
             "compacted_keys": self.compacted_keys,
@@ -795,7 +807,7 @@ class DedupSession:
             raise ValueError("one-shot session already finalized")
         base = self.allocator.allocate(len(token_lists))
         self._impl.merge((base, token_lists, np.asarray(sig),
-                          np.asarray(bands)), index=False)
+                          np.asarray(bands), None), index=False)
         self._finalized = True
         return self.snapshot()
 
@@ -915,14 +927,16 @@ class DedupSession:
 
     # -- shared backend plumbing -------------------------------------------
 
-    def _retain(self, token_lists, sig: np.ndarray) -> None:
+    def _retain(self, token_lists, sig: np.ndarray, sig_dev=None) -> None:
         """Grow the session verifier with one chunk's docs.
 
         The verifier owns the retained state ("row i == doc i"): the
         first chunk builds it — padded with blank rows for any ids
         below the chunk's base (``doc_id_base`` sessions; those ids
         have no band rows, so they can never become candidates) — and
-        later chunks extend it in place.
+        later chunks extend it in place.  ``sig_dev``, the chunk's
+        signatures still on the device, goes into a device verifier's
+        store without a copy from the host.
         """
         if self._external_verifier:
             return
@@ -935,17 +949,19 @@ class DedupSession:
                     self._verifier = ExactJaccardVerifier.from_token_lists(
                         [[]] * gap + list(token_lists), cfg.ngram)
                     return
-                full = sig if gap == 0 else np.concatenate(
-                    [np.zeros((gap, sig.shape[1]), dtype=sig.dtype), sig])
                 cls = (DeviceScoredEdgeVerifier
                        if self.backend == "sharded"
                        and self._impl.stage2 == "device"
                        else SignatureVerifier)
-                self._verifier = cls(full, backend=cfg.resolved_backend())
+                self._verifier = cls(
+                    np.zeros((gap, sig.shape[1]), dtype=sig.dtype),
+                    backend=cfg.resolved_backend(),
+                    capacity=cfg.sig_store_capacity)
+                self._verifier.extend_signatures(sig, device_rows=sig_dev)
             elif self._wants_exact():
                 self._verifier.extend_token_lists(token_lists)
             else:
-                self._verifier.extend_signatures(sig)
+                self._verifier.extend_signatures(sig, device_rows=sig_dev)
 
     def _wants_exact(self) -> bool:
         return self.backend == "host" and self.config.exact_verification
@@ -964,10 +980,11 @@ class DedupSession:
             return self._verifier
         if not hasattr(self, "_est_verifier"):
             self._est_verifier = SignatureVerifier(
-                self._verifier.signatures,
+                np.zeros((0, self.config.num_hashes), dtype=np.uint32),
                 backend=self.config.resolved_backend())
-        # Re-adopt buffer + slot layout every use: chunk extensions
-        # regrow the matrix and retention sweeps rewrite rows in place.
+        # Re-adopt buffer, slot layout and device store every use: chunk
+        # extensions regrow the matrix and retention sweeps rewrite rows
+        # in place.
         self._est_verifier.adopt_layout(self._verifier)
         return self._est_verifier
 
@@ -1010,15 +1027,17 @@ class _HostBackend:
                     else list(chunk))
             base = sess.allocator.allocate(len(docs))
             if not docs:
-                return (base, docs, None, None)
+                return (base, docs, None, None, None)
             pad = shingle.pow2_bucket(
                 max(len(d.encode("utf-8")) for d in docs) + 1)
-            sig, bands = self.pipe.compute_arrays_bytes(docs, pad_len=pad)
-            return (base, docs, sig, bands)
+            # The signatures stay on the device too, for the store of a
+            # device verify backend.
+            return (base, docs) + self.pipe.compute_arrays_bytes(
+                docs, pad_len=pad, keep_device=True)
         toks = chunk if tokenized else self.pipe.tokenize(chunk)
         base = sess.allocator.allocate(len(toks))
         if not toks:
-            return (base, toks, None, None)
+            return (base, toks, None, None, None)
         # Fused-ingest configs compute both arrays in one Pallas pass.
         # The token dim buckets to a power of two so repeated chunked
         # ingests reuse a bounded jit-compile set instead of paying one
@@ -1026,14 +1045,14 @@ class _HostBackend:
         # bug, on the write path); signatures are padding-invariant.
         pad = shingle.pow2_bucket(max((len(t) for t in toks), default=1))
         sig, bands = self.pipe.compute_arrays(toks, pad_len=pad)
-        return (base, toks, sig, bands)
+        return (base, toks, sig, bands, None)
 
     def merge(self, pending, index: bool = True):
-        base, toks, sig, bands = pending
+        base, toks, sig, bands, sig_dev = pending
         if sig is None:
             return
         sess = self.sess
-        sess._retain(toks, sig)
+        sess._retain(toks, sig, sig_dev)
         sess.n_merged = base + len(toks)
         sess.acc.grow(sess.n_docs)
         sess.acc.feed(BandMatrixSource(bands, doc_id_base=base),

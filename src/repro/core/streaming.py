@@ -205,7 +205,8 @@ class StreamingDedup:
         for i, row in self._sig_cache.items():
             sig[i] = row
         return SignatureVerifier(
-            sig, backend=self.config.resolved_backend())
+            sig, backend=self.config.resolved_backend(),
+            capacity=self.config.sig_store_capacity)
 
     def cluster(self, edge_threshold: float | None = None,
                 tree_threshold: float | None = None,

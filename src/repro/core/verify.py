@@ -34,15 +34,23 @@ CallbackVerifier     compat shim around a scalar ``fn(a, b) -> float``
 
 All verifiers record ``n_batches`` / ``n_pairs`` / ``seconds`` so
 drivers and benchmarks can report batched-verification throughput.
+
+The device backends of ``SignatureVerifier`` keep the retained rows in
+a ``SignatureStore``: one device buffer that new rows are written into
+in place, so a chunk copies only its own rows to the device.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from repro.core import spans
+from repro.core.shingle import pow2_bucket
 
 
 class BatchVerifier:
@@ -97,48 +105,160 @@ class CallbackVerifier(BatchVerifier):
         )
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _store_block(buf, rows, start):
+    """``rows`` written into ``buf`` from row ``start`` on, in place."""
+    return jax.lax.dynamic_update_slice(buf, rows.astype(buf.dtype),
+                                        (start, 0))
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _store_slots(buf, rows, slots):
+    """Row i of ``rows`` written into row ``slots[i]`` of ``buf``."""
+    return buf.at[slots, :rows.shape[1]].set(rows.astype(buf.dtype))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _store_grown(buf, capacity):
+    """``buf``'s rows at the top of a zero buffer of ``capacity`` rows."""
+    return jax.lax.dynamic_update_slice(
+        jnp.zeros((capacity, buf.shape[1]), buf.dtype), buf, (0, 0))
+
+
+LANES = 128
+
+
+class SignatureStore:
+    """Retained signature rows on the device: one (capacity, W) uint32
+    buffer, zero past the rows written, whose rows hold the ``width``
+    hashes of a signature padded to whole 128-lane tiles (W = 128 for
+    M = 112).  The TPU lays such a buffer out row-major, as a row
+    gather reads it; a (capacity, 112) buffer it lays out column-major,
+    and every gather from it would first copy the whole store.
+
+    ``write`` copies rows into the buffer in place (the old buffer is
+    donated), from the host or from a device array that already holds
+    them, inside a ``dedup.sig_store`` span whose ``h2d_bytes`` counts
+    what it copied to the device: the rows when they come from the
+    host, and the row offset or the slot indices.  With a fixed
+    ``capacity`` the buffer never changes shape, so a verify program
+    over it compiles once per batch shape; with capacity 0 it doubles
+    when a write passes its end, copying the old rows on the device.
+    """
+
+    def __init__(self, width: int, capacity: int = 0):
+        self.fixed = capacity > 0
+        self.width = int(width)
+        lanes = -(-self.width // LANES) * LANES
+        self.buf = jnp.zeros((int(capacity), lanes), jnp.uint32)
+
+    @property
+    def capacity(self) -> int:
+        return self.buf.shape[0]
+
+    def _reserve(self, rows: int) -> None:
+        if rows <= self.capacity:
+            return
+        if self.fixed:
+            raise ValueError(
+                f"the signature store holds {self.capacity} rows and "
+                f"{rows} are needed; raise DedupConfig.sig_store_capacity")
+        self.buf = _store_grown(self.buf, max(rows, 2 * self.capacity))
+
+    def write(self, rows, *, start: int | None = None, slots=None,
+              device_rows=None) -> None:
+        """Write row i of ``rows`` at ``start + i``, or at ``slots[i]``.
+
+        ``device_rows``, when given, is a device array holding the same
+        rows; nothing of them is copied from the host then.
+        """
+        with spans.span("sig_store") as sp:
+            h2d = 0
+            if device_rows is None:
+                rows = np.asarray(rows, dtype=np.uint32)
+                device_rows = jax.device_put(rows)
+                h2d += rows.nbytes
+            if slots is None:
+                self._reserve(start + device_rows.shape[0])
+                offset = np.int32(start)
+                self.buf = _store_block(self.buf, device_rows, offset)
+                h2d += offset.nbytes
+            else:
+                slots = np.asarray(slots, dtype=np.int32)
+                self._reserve(int(slots.max()) + 1)
+                self.buf = _store_slots(self.buf, device_rows, slots)
+                h2d += slots.nbytes
+            sp.count(h2d_bytes=h2d)
+
+
 class SignatureVerifier(BatchVerifier):
     """Signature-agreement estimate over gathered signature rows.
 
     ``backend``:
       * ``"numpy"`` — host vectorized ``(sig[a] == sig[b]).mean(-1)``.
-      * ``"jnp"``   — jitted gather + agreement count on device;
-        batches are padded to power-of-two buckets so the jit cache
-        stays small.
+      * ``"jnp"``   — jitted gather + agreement count on device.
       * ``"pallas"`` — ``kernels.sigjaccard.indexed_pair_counts`` TPU
-        kernel (interpret mode on CPU), same shape bucketing.
+        kernel (interpret mode on CPU).
       Both device backends divide the counts by M in numpy
-      (``device_estimate``), bit-identical to ``"numpy"``.
+      (``device_estimate``), bit-identical to ``"numpy"``.  They keep
+      the retained rows in a ``SignatureStore`` of ``capacity`` rows
+      (0: grown by doubling) beside the host matrix, and write each
+      extension into it.  Over a fixed capacity the verify program's
+      shapes are the pair buckets of ``device_estimate`` up to
+      ``batch_pairs``, and the store's creation compiles every one, so
+      a later batch of a size set-up never met compiles nothing.
     """
 
     def __init__(self, signatures: np.ndarray, backend: str = "numpy",
-                 batch_pairs: int = 8192):
+                 batch_pairs: int = 8192, capacity: int = 0):
         super().__init__()
         if backend not in ("numpy", "jnp", "pallas"):
             raise ValueError(f"unknown backend {backend!r}")
         self.backend = backend
         self.batch_pairs = int(batch_pairs)
+        self.capacity = int(capacity)
         self._set_signatures(np.asarray(signatures))
 
-    def _set_signatures(self, sig: np.ndarray):
+    def _set_signatures(self, sig: np.ndarray, device_rows=None):
         # The matrix is adopted as the growth buffer; extensions write
         # past ``_n_rows`` after a capacity-doubling copy, so repeated
-        # chunk appends are amortized O(chunk), and the device copy
-        # (jnp/pallas backends) is refreshed lazily at the next verify.
-        # Row i holds doc i until the first ``release_rows`` call, which
-        # switches the verifier to an explicit doc -> slot map with a
-        # free-slot pool (retention layer, DESIGN.md §7).
+        # chunk appends are amortized O(chunk).  The device backends
+        # write the same rows into their store.  Row i holds doc i
+        # until the first ``release_rows`` call, which switches the
+        # verifier to an explicit doc -> slot map with a free-slot pool
+        # (retention layer, DESIGN.md §7).
         self._buf = sig
         self._n_rows = len(sig)
         self.signatures = sig
-        self._dev_dirty = True
         self._slot_of: dict[int, int] | None = None
         self._free: list[int] = []
         self._n_docs = len(sig)
-        # Bumped on every mutation (extend/release/reset) so a sharing
-        # view (``adopt_layout``) can invalidate its device copy only
-        # when the matrix actually changed.
-        self._mutations = getattr(self, "_mutations", 0) + 1
+        self._store: SignatureStore | None = None
+        if len(sig):
+            self._device_write(sig, device_rows, start=0)
+
+    def _device_write(self, rows: np.ndarray, device_rows, *,
+                      start: int | None = None, slots=None) -> None:
+        if self.backend == "numpy":
+            return
+        if self._store is None:
+            self._store = SignatureStore(rows.shape[1], self.capacity)
+            if self._store.fixed:
+                self._compile_buckets()
+        self._store.write(rows, start=start, slots=slots,
+                          device_rows=device_rows)
+
+    def _compile_buckets(self) -> None:
+        """Run the verify program once at every pair bucket up to
+        ``batch_pairs`` over the (still empty) store."""
+        bucket = pow2_bucket(1)
+        while True:
+            zeros = np.zeros(bucket, dtype=np.int32)
+            device_estimate(self.backend, self._store.buf, zeros, zeros,
+                            self._store.width)
+            if bucket >= self.batch_pairs:
+                return
+            bucket *= 2
 
     # -- retention (free-slot pool) ----------------------------------------
 
@@ -185,17 +305,23 @@ class SignatureVerifier(BatchVerifier):
                 raise KeyError(f"doc {d} has no retained row to release")
             self._free.append(slot)
             released += 1
-        self._mutations += 1
         return released
 
     def adopt_layout(self, other: "SignatureVerifier") -> None:
-        """Share ``other``'s retained matrix and slot layout (zero-copy).
+        """Share ``other``'s retained matrix, slot layout and device
+        store (zero-copy).
 
         The session keeps a plain-estimator view over a
         ``DeviceScoredEdgeVerifier``'s matrix for host-generated edges;
         eviction mutates rows in place, so the view must re-adopt the
-        owner's buffer/slot state before each use.
+        owner's buffer/slot state before each use.  The owner writes
+        its extensions and slot rewrites into the one device store both
+        read, so nothing is copied to the device for the view.
         """
+        if (self.backend == "numpy") != (other.backend == "numpy"):
+            raise ValueError(
+                "adopt_layout shares the device store: both verifiers "
+                "need a host backend or both a device backend")
         if self.signatures is not other.signatures:
             self._buf = other._buf
             self._n_rows = other._n_rows
@@ -203,13 +329,7 @@ class SignatureVerifier(BatchVerifier):
         self._slot_of = other._slot_of
         self._free = other._free
         self._n_docs = other._n_docs
-        # Slot reuse rewrites rows without replacing the array object,
-        # so object identity alone cannot tell whether the device copy
-        # is stale — the owner's mutation counter can (and it spares
-        # jnp/pallas backends a full re-upload on every adopt).
-        if getattr(self, "_adopted_mutations", None) != other._mutations:
-            self._dev_dirty = True
-            self._adopted_mutations = other._mutations
+        self._store = other._store
 
     def rows_for(self, doc_ids) -> np.ndarray:
         """Retained signature rows for ``doc_ids`` (eviction-aware)."""
@@ -235,15 +355,7 @@ class SignatureVerifier(BatchVerifier):
             return self.signatures, None
         return self.signatures.copy(), dict(self._slot_of)
 
-    def _device_signatures(self):
-        import jax.numpy as jnp
-
-        if self._dev_dirty:
-            self._sig_dev = jnp.asarray(self.signatures)
-            self._dev_dirty = False
-        return self._sig_dev
-
-    def extend_signatures(self, rows: np.ndarray) -> None:
+    def extend_signatures(self, rows: np.ndarray, device_rows=None) -> None:
         """Append signature rows for newly ingested docs.
 
         Incremental ingest (``core.session.DedupSession``) allocates
@@ -252,13 +364,15 @@ class SignatureVerifier(BatchVerifier):
         allocation order.  Throughput counters (and, for
         ``DeviceScoredEdgeVerifier``, the registered device scores)
         survive the extension — the session keeps ONE verifier alive
-        across every chunk.
+        across every chunk.  ``device_rows``, a device array holding
+        the same rows, spares the device backends the copy from the
+        host.
         """
         rows = np.asarray(rows)
         if rows.size == 0:
             return
         if self.signatures.size == 0:
-            self._set_signatures(rows)
+            self._set_signatures(rows, device_rows)
             return
         if rows.shape[-1] != self.signatures.shape[-1]:
             raise ValueError(
@@ -275,6 +389,7 @@ class SignatureVerifier(BatchVerifier):
                                dtype=self._buf.dtype)
                 buf[: self._n_rows] = self._buf[: self._n_rows]
                 self._buf = buf
+            slots = []
             for row in rows:
                 if self._free:
                     slot = self._free.pop()
@@ -284,23 +399,23 @@ class SignatureVerifier(BatchVerifier):
                 self._buf[slot] = row
                 self._slot_of[self._n_docs] = slot
                 self._n_docs += 1
+                slots.append(slot)
             self.signatures = self._buf[: self._n_rows]
-            self._dev_dirty = True
-            self._mutations += 1
+            self._device_write(rows, device_rows, slots=slots)
             return
-        n_new = self._n_rows + len(rows)
+        n0 = self._n_rows
+        n_new = n0 + len(rows)
         if n_new > len(self._buf):
             cap = max(n_new, 2 * max(1, len(self._buf)))
             buf = np.empty((cap, self._buf.shape[1]),
                            dtype=self._buf.dtype)
-            buf[: self._n_rows] = self._buf[: self._n_rows]
+            buf[:n0] = self._buf[:n0]
             self._buf = buf
-        self._buf[self._n_rows : n_new] = rows
+        self._buf[n0:n_new] = rows
         self._n_rows = n_new
         self._n_docs = n_new
         self.signatures = self._buf[: self._n_rows]
-        self._dev_dirty = True
-        self._mutations += 1
+        self._device_write(rows, device_rows, start=n0)
 
     def _verify_batch(self, pairs: np.ndarray) -> np.ndarray:
         pairs = self._slot_index(np.asarray(pairs))
@@ -309,41 +424,43 @@ class SignatureVerifier(BatchVerifier):
             a = self.signatures[a_idx]
             b = self.signatures[b_idx]
             return (a == b).mean(axis=-1, dtype=np.float32)
-        import jax.numpy as jnp
-
-        # Pad to the next power-of-two bucket (>= 256): stable, bounded
-        # set of jit shapes without padding every run-sized batch to the
-        # full batch_pairs.
-        p = len(pairs)
-        bucket = 256
-        while bucket < p:
-            bucket *= 2
-        a_idx = jnp.asarray(np.pad(a_idx, (0, bucket - p)))
-        b_idx = jnp.asarray(np.pad(b_idx, (0, bucket - p)))
-        sig_dev = self._device_signatures()
-        return device_estimate(self.backend, sig_dev, a_idx, b_idx)[:p]
+        return device_estimate(self.backend, self._store.buf, a_idx,
+                               b_idx, self._store.width)
 
 
-@jax.jit
-def _gather_counts_jit(sig, a_idx, b_idx):
-    """Fused gather + agreement counts (one dispatch per bucket)."""
-    return jnp.sum((sig[a_idx] == sig[b_idx]).astype(jnp.float32), axis=-1)
+@functools.partial(jax.jit, static_argnums=3)
+def _gather_counts_jit(sig, a_idx, b_idx, width=None):
+    """Fused gather + agreement counts over each row's leading ``width``
+    hashes (one dispatch per bucket)."""
+    return jnp.sum((sig[a_idx][:, :width] == sig[b_idx][:, :width]
+                    ).astype(jnp.float32), axis=-1)
 
 
-def device_estimate(backend: str, sig_dev, a_idx, b_idx) -> np.ndarray:
-    """m/M estimates of (a, b) row pairs, agreement counted on device.
+def device_estimate(backend: str, sig_dev, a_idx, b_idx,
+                    width: int | None = None) -> np.ndarray:
+    """m/M estimates of (a, b) row pairs, agreement counted on device
+    over each row's leading ``width`` hashes (M; default every column).
 
-    The device returns exact counts and numpy divides by M, so the
-    ``jnp`` and ``pallas`` backends are bit-identical to the ``numpy``
-    estimator (a division on the device is not correctly rounded).
+    The host index arrays are padded with row 0 to their power-of-two
+    bucket (at least 256), so the verify program compiles once per
+    bucket, not once per batch size.  The device returns exact counts
+    and numpy divides by M, so the ``jnp`` and ``pallas`` backends are
+    bit-identical to the ``numpy`` estimator (a division on the device
+    is not correctly rounded).
     """
+    p = len(a_idx)
+    pad = (0, pow2_bucket(p) - p)
+    a_idx = jnp.asarray(np.pad(np.asarray(a_idx), pad))
+    b_idx = jnp.asarray(np.pad(np.asarray(b_idx), pad))
+    width = sig_dev.shape[1] if width is None else width
     if backend == "jnp":
-        counts = _gather_counts_jit(sig_dev, a_idx, b_idx)
+        counts = _gather_counts_jit(sig_dev, a_idx, b_idx, width)
     else:
         from repro.kernels import ops as kops
 
-        counts = kops.indexed_pair_counts(sig_dev, a_idx, b_idx)
-    return np.asarray(counts) / np.float32(sig_dev.shape[1])
+        counts = kops.indexed_pair_counts(sig_dev, a_idx, b_idx,
+                                          width=width)
+    return np.asarray(counts)[:p] / np.float32(width)
 
 
 class ShardedEdgeVerifier(SignatureVerifier):
@@ -400,9 +517,9 @@ class DeviceScoredEdgeVerifier(ShardedEdgeVerifier):
     """
 
     def __init__(self, signatures: np.ndarray, backend: str = "numpy",
-                 batch_pairs: int = 8192):
+                 batch_pairs: int = 8192, capacity: int = 0):
         super().__init__(signatures, backend=backend,
-                         batch_pairs=batch_pairs)
+                         batch_pairs=batch_pairs, capacity=capacity)
         self._scores: dict[tuple[int, int], float] = {}
         self.n_passthrough = 0
         self.n_rescored = 0

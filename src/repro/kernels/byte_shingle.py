@@ -169,6 +169,7 @@ def byte_token_hashes(
         ],
         scratch_shapes=[pltpu.VMEM((tlb_, td_), jnp.int32)],
         interpret=interpret,
+        name="byte_shingle",
     )(buf, ln)
     return tok.T[:D, :LB], ends.T[:D, :LB]
 

@@ -56,15 +56,19 @@ def _counts_rows(sig_a, sig_b, valid, tp: int, interpret: bool | None):
         out_specs=pl.BlockSpec((tp_, 1), lambda p: (p, 0)),
         out_shape=jax.ShapeDtypeStruct((Pp, 1), jnp.float32),
         interpret=interpret,
+        name="sigjaccard_counts",
     )(a, b, v)
     return out[:P, 0]
 
 
-def _gather_rows(sig, a_idx, b_idx):
-    """Row gather with indices clipped to the local row range."""
+def _gather_rows(sig, a_idx, b_idx, width=None):
+    """Row gather with indices clipped to the local row range; with
+    ``width``, only each row's leading ``width`` hashes (a matrix whose
+    rows are padded to whole lanes)."""
     D = sig.shape[0]
-    return (sig[jnp.clip(a_idx, 0, D - 1)],
-            sig[jnp.clip(b_idx, 0, D - 1)])
+    cols = slice(None) if width is None else slice(0, width)
+    return (sig[jnp.clip(a_idx, 0, D - 1)][:, cols],
+            sig[jnp.clip(b_idx, 0, D - 1)][:, cols])
 
 
 @functools.partial(jax.jit, static_argnames=("tp", "interpret"))
@@ -80,24 +84,29 @@ def pair_counts(
     return _counts_rows(sig_a, sig_b, valid, tp, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("tp", "interpret"))
+@functools.partial(jax.jit, static_argnames=("width", "tp", "interpret"))
 def indexed_pair_counts(
     sig: jnp.ndarray,
     a_idx: jnp.ndarray,
     b_idx: jnp.ndarray,
     *,
+    width: int | None = None,
     tp: int = TP,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused gather + agreement counts: one dispatch per index batch.
 
-    sig (D, M) uint32, a_idx/b_idx (P,) int -> (P,) float32 counts.
-    The row gather runs on device inside the same jit as the kernel, so
+    sig (D, W) uint32, a_idx/b_idx (P,) int -> (P,) float32 counts over
+    the leading ``width`` (default W) hashes of each row.  The row
+    gather runs on device inside the same jit as the kernel, so
     verifiers never materialize the gathered operands on the host; they
-    divide the counts by M in numpy.
+    divide the counts by M in numpy.  A signature store keeps its rows
+    padded to whole 128-lane tiles (M=112 padded to 128), which the TPU
+    lays out row-major; a (D, 112) matrix it would lay out column-major
+    and copy whole into row order on every call.
     """
     valid = jnp.ones(a_idx.shape, jnp.int32)
-    return _counts_rows(*_gather_rows(sig, a_idx, b_idx), valid, tp,
+    return _counts_rows(*_gather_rows(sig, a_idx, b_idx, width), valid, tp,
                         interpret)
 
 

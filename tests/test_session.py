@@ -321,6 +321,44 @@ def test_band_index_cross_step_edges():
         idx.match_then_insert(np.zeros((1, 3, 2), np.uint32), 9)
 
 
+def test_band_index_holds_no_list_per_single_doc_bucket():
+    """A bucket of one doc is the bare id, so the index adds no object
+    a garbage collection walks per (band, doc): such a collection's
+    pause no longer grows with the index."""
+    import gc
+
+    rng = np.random.default_rng(0)
+    bands = rng.integers(0, 2**32, size=(4000, 14, 2),
+                         dtype=np.uint64).astype(np.uint32)
+    bands[3000] = bands[3]                   # collides in every band
+    idx = BandIndex(14)
+    gc.collect()
+    before = len(gc.get_objects())
+    assert len(idx.match_then_insert(bands[:2000], 0)) == 0
+    edges = idx.match_then_insert(bands[2000:], 2000)
+    gc.collect()
+    assert len(gc.get_objects()) - before < 200     # not 14 x 4,000
+    assert edges.tolist() == [[3, 3000]] * 14
+    assert idx.stats()["n_entries"] == 14 * 4000
+    key = (int(bands[3, 0, 0]), int(bands[3, 0, 1]))
+    maps = idx.export_maps()
+    assert maps[0][key] == (3, 3000)
+    other = (int(bands[7, 0, 0]), int(bands[7, 0, 1]))
+    assert maps[0][other] == (7,)
+
+
+def test_band_index_evicts_a_single_doc_bucket_onto_its_root():
+    idx = BandIndex(1, track_entries=True)
+    idx.match_then_insert(np.array([[[1, 1]], [[2, 2]]], np.uint32), 0)
+    idx.evict([1], lambda d: 0)              # doc 1 deposed under 0
+    assert idx.export_maps()[0][(2, 2)] == (0,)
+    edges = idx.match_then_insert(np.array([[[2, 2]]], np.uint32), 2)
+    assert edges.tolist() == [[0, 2]]
+    idx.evict([0], lambda d: 2)              # the re-homed entry moves on
+    assert idx.export_maps()[0][(1, 1)] == (2,)
+    assert idx.export_maps()[0][(2, 2)] == (2,)
+
+
 # -- order invariance of ClusterAccumulator --------------------------------
 
 def _run_order_invariance(seed: int, n_docs: int, n_edges: int,

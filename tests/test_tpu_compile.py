@@ -66,35 +66,55 @@ def test_fused_ingest_compiles(spec, width):
         spec((M,), jnp.uint32))
 
 
-def test_bytes_to_bands_compiles(spec):
+# (n, M, r): the paper's widths, and FineWeb's MinHash settings (word
+# 5-grams, 112 hashes in 14 bands of 8), whose M is not a multiple of
+# the 128 lanes.
+WIDTHS = [pytest.param(8, M, 2, id="paper"),
+          pytest.param(5, 112, 8, id="fineweb")]
+
+
+@pytest.mark.parametrize("n,m,r", WIDTHS)
+def test_bytes_to_bands_compiles(spec, n, m, r):
     from repro.kernels.byte_shingle import bytes_to_bands
 
     # Two kernels: the byte tokenizer and the fused ingest it feeds.
     _assert_kernel(
-        lambda d, ln, s: bytes_to_bands(d, ln, s, interpret=False),
+        lambda d, ln, s: bytes_to_bands(d, ln, s, n=n, r=r,
+                                        interpret=False),
         spec((256, 2048), jnp.uint8), spec((256,), jnp.int32),
-        spec((M,), jnp.uint32), count=2)
+        spec((m,), jnp.uint32), count=2)
 
 
-@pytest.mark.parametrize("entry", [
-    "pair_counts", "indexed_pair_counts", "masked_pair_counts",
-    "masked_indexed_pair_counts"])
-def test_sigjaccard_compiles(spec, entry):
+@pytest.mark.parametrize("entry,m,store,pairs", [
+    pytest.param("pair_counts", M, None, 4096, id="pair_counts"),
+    pytest.param("indexed_pair_counts", M, (8192, M), 4096,
+                 id="indexed_pair_counts"),
+    pytest.param("masked_pair_counts", M, None, 4096,
+                 id="masked_pair_counts"),
+    pytest.param("masked_indexed_pair_counts", M, (8192, M), 4096,
+                 id="masked_indexed_pair_counts"),
+    # The largest verify batch over the admission_bytes cell's device
+    # signature store at FineWeb's widths: 720,896 rows of 112 hashes,
+    # padded to 128 lanes.
+    pytest.param("indexed_pair_counts", 112, (720_896, 128), 8192,
+                 id="indexed_pair_counts-fineweb")])
+def test_sigjaccard_compiles(spec, entry, m, store, pairs):
     from repro.kernels import sigjaccard
 
     fn = getattr(sigjaccard, entry)
-    P = 4096
-    rows = spec((P, M), jnp.uint32)
+    P = pairs
+    rows = spec((P, m), jnp.uint32)
     idx = spec((P,), jnp.int32)
     valid = spec((P,), jnp.bool_)
+    kw = {"width": m} if store and store[1] != m else {}
+    store = spec(store or (0, m), jnp.uint32)
     args = {
         "pair_counts": (rows, rows),
-        "indexed_pair_counts": (spec((2 * P, M), jnp.uint32), idx, idx),
+        "indexed_pair_counts": (store, idx, idx),
         "masked_pair_counts": (rows, rows, valid),
-        "masked_indexed_pair_counts": (spec((2 * P, M), jnp.uint32), idx,
-                                       idx, valid),
+        "masked_indexed_pair_counts": (store, idx, idx, valid),
     }[entry]
-    _assert_kernel(lambda *a: fn(*a, interpret=False), *args)
+    _assert_kernel(lambda *a: fn(*a, interpret=False, **kw), *args)
 
 
 @pytest.mark.parametrize("stage", ["ngram", "minhash", "bandfold"])
