@@ -25,7 +25,10 @@ of the previous three copies is gone.
   next run's root compression, so the exclusion statistics (paper
   Table 5) and the union-find lower-bound guarantee are unchanged.
 * ``"band"`` — flush at band boundaries (or when the buffer reaches
-  ``max_batch_pairs``).  Larger dispatches, maximum throughput; pairs
+  ``max_batch_pairs``).  With disjoint sets the runs between two
+  flushes are compressed and paired in array passes
+  (``ClusterAccumulator._feed_arrays``), with the loop's results bit
+  for bit.  Larger dispatches, maximum throughput; pairs
   that a same-band union would have excluded may be evaluated, and a
   union's ``sim`` is the one measured against collection-time roots, so
   the tree-threshold guarantee becomes approximate (audit with
@@ -80,6 +83,49 @@ def _sort_keys(ab: np.ndarray) -> np.ndarray:
     return (ab[:, 0] << 32) | ab[:, 1]
 
 
+def _in_sorted(sorted_keys: np.ndarray, keys: np.ndarray) -> np.ndarray:
+    """``keys`` found in the sorted array ``sorted_keys``, lane for lane."""
+    if not len(sorted_keys):
+        return np.zeros(len(keys), bool)
+    at = np.searchsorted(sorted_keys, keys)
+    return sorted_keys[np.minimum(at, len(sorted_keys) - 1)] == keys
+
+
+def _merge_sorted(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Two sorted key arrays as one sorted array."""
+    return np.insert(a, np.searchsorted(a, b), b)
+
+
+def _pairs_of(keys: list[np.ndarray]) -> np.ndarray:
+    """The (P, 2) pairs of a list of ``_sort_keys`` arrays, in order."""
+    keys = np.concatenate(keys) if keys else np.zeros(0, np.int64)
+    return np.stack([keys >> 32, keys & 0xFFFFFFFF], axis=-1)
+
+
+def _run_root_pairs(uf: ThresholdUnionFind, docs: np.ndarray,
+                    starts: np.ndarray, ends: np.ndarray):
+    """Every run ``docs[s:e]`` compressed to its sorted unique roots,
+    and their pairs in row-major order, runs in order: the pairs the
+    scalar loop walks, as ``_sort_keys`` and each pair's run index."""
+    m = ends - starts
+    run = np.repeat(np.arange(len(m)), m)
+    at = np.arange(len(run)) + np.repeat(starts - (np.cumsum(m) - m), m)
+    roots = uf.find_many(docs[at])
+    order = np.lexsort((roots, run))
+    run, roots = run[order], roots[order]
+    uniq = np.ones(len(roots), bool)
+    uniq[1:] = (run[1:] != run[:-1]) | (roots[1:] != roots[:-1])
+    run, roots = run[uniq], roots[uniq]
+    # Each root pairs with the roots after it in its run: k - 1 - its
+    # place among the run's k roots.
+    k = np.bincount(run, minlength=len(m))
+    after = np.repeat(np.cumsum(k), k) - 1 - np.arange(len(roots))
+    left = np.repeat(np.arange(len(roots)), after)
+    right = (left + 1 + np.arange(len(left))
+             - np.repeat(np.cumsum(after) - after, after))
+    return (roots[left] << 32) | roots[right], run[left]
+
+
 class PairList(Sequence):
     """Verified ``(a, b, sim)`` triples sorted by ``(a, b)``: an
     immutable sequence over two columns, which it makes read-only.
@@ -91,7 +137,7 @@ class PairList(Sequence):
     or a tuple as the list of those tuples would.
     """
 
-    __slots__ = ("ab", "sim")
+    __slots__ = ("ab", "sim", "_keys")
     __hash__ = None
 
     def __init__(self, ab: np.ndarray, sim: np.ndarray):
@@ -99,6 +145,15 @@ class PairList(Sequence):
         sim.setflags(write=False)
         self.ab = ab
         self.sim = sim
+        self._keys = None
+
+    @property
+    def keys(self) -> np.ndarray:
+        """``_sort_keys`` of the pairs, sorted (made on first use)."""
+        if self._keys is None:
+            self._keys = _sort_keys(self.ab)
+            self._keys.setflags(write=False)
+        return self._keys
 
     @classmethod
     def empty(cls) -> "PairList":
@@ -220,16 +275,21 @@ class ClusterAccumulator:
 
         Folds in only the pairs evaluated since the last read; a list
         returned earlier keeps its contents."""
-        n = len(self.evaluated) - len(self._sorted)
-        if n:
-            start = len(self._sorted)
-            ab = np.fromiter(
-                chain.from_iterable(islice(self.evaluated, start, None)),
-                dtype=np.int64, count=2 * n).reshape(n, 2)
-            sim = np.fromiter(islice(self.evaluated.values(), start, None),
-                              dtype=np.float64, count=n)
+        ab = self._unsorted()
+        if len(ab):
+            sim = np.fromiter(
+                islice(self.evaluated.values(), len(self._sorted), None),
+                dtype=np.float64, count=len(ab))
             self._sorted = self._sorted.merged(ab, sim)
         return self._sorted
+
+    def _unsorted(self) -> np.ndarray:
+        """(n, 2) evaluated pairs not in the sorted list yet, in order."""
+        start = len(self._sorted)
+        n = len(self.evaluated) - start
+        return np.fromiter(
+            chain.from_iterable(islice(self.evaluated, start, None)),
+            dtype=np.int64, count=2 * n).reshape(n, 2)
 
     @property
     def num_docs(self) -> int:
@@ -261,69 +321,177 @@ class ClusterAccumulator:
             # is reused (e.g. re-clustering at a second threshold).
             batches0, seconds0 = verifier.n_batches, verifier.seconds
             stats = ClusterStats()
-            pending: list[tuple[int, int]] = []
-            pending_set: set[tuple[int, int]] = set()
 
-            def flush():
-                if not pending:
-                    return
-                sims = verifier(np.array(pending, dtype=np.int64))
-                for (a, c), sim in zip(pending, sims):
-                    sim = float(sim)
-                    evaluated[(a, c)] = sim
-                    stats.pairs_evaluated += 1
-                    if sim > self.edge_threshold:
-                        stats.pairs_above_edge += 1
-                        if self.use_disjoint_sets:
-                            before = uf.n_unions
-                            uf.union(a, c, sim)
-                            if uf.n_unions > before:
-                                stats.unions_done += 1
-                            else:
-                                stats.unions_rejected += 1
-                pending.clear()
-                pending_set.clear()
-
-            for band_runs in source.iter_bands():
-                for members in band_runs.iter_groups():
-                    m = len(members)
-                    stats.pairs_generated += m * (m - 1) // 2
-                    if self.use_disjoint_sets:
-                        # "replace D with D.find()" — compress to roots.
-                        uniq = np.unique([uf.find(int(d)) for d in members])
+            def flush(pairs: np.ndarray) -> bool:
+                """Verify (P, 2) pending pairs, record them in order and
+                union those above the edge threshold; True if any
+                union went through."""
+                if not len(pairs):
+                    return False
+                sims = np.asarray(verifier(pairs), np.float64)
+                evaluated.update(zip(
+                    zip(pairs[:, 0].tolist(), pairs[:, 1].tolist()),
+                    sims.tolist()))
+                stats.pairs_evaluated += len(pairs)
+                above = np.flatnonzero(sims > self.edge_threshold)
+                stats.pairs_above_edge += len(above)
+                if not self.use_disjoint_sets:
+                    return False
+                unions0 = uf.n_unions
+                for i in above.tolist():
+                    before = uf.n_unions
+                    uf.union(int(pairs[i, 0]), int(pairs[i, 1]),
+                             sims[i].item())
+                    if uf.n_unions > before:
+                        stats.unions_done += 1
                     else:
-                        uniq = np.sort(members)
-                    k = len(uniq)
-                    if k < 2:
-                        # All members already co-clustered: all excluded.
-                        stats.pairs_excluded += m * (m - 1) // 2
-                        continue
-                    # Pairs collapsed by prior clustering are excluded too.
-                    stats.pairs_excluded += (m * (m - 1) // 2
-                                             - k * (k - 1) // 2)
-                    for ii in range(k):
-                        for jj in range(ii + 1, k):
-                            key = (int(uniq[ii]), int(uniq[jj]))
-                            if key in evaluated or key in pending_set:
-                                stats.pairs_excluded += 1
-                                continue
-                            pending.append(key)
-                            pending_set.add(key)
-                    if self.batch == "run" or \
-                            len(pending) >= self.max_batch_pairs:
-                        flush()
-                if self.batch == "band":
-                    flush()
-            flush()
+                        stats.unions_rejected += 1
+                return uf.n_unions > unions0
+
+            if self.batch == "band" and self.use_disjoint_sets:
+                groups, recompressed = self._feed_arrays(
+                    source, stats, flush)
+            else:
+                groups, recompressed = self._feed_loop(
+                    source, stats, flush)
 
             stats.verify_batches = verifier.n_batches - batches0
             stats.verify_seconds = verifier.seconds - seconds0
             # The verify calls inside this feed, as a stat of the span
             # rather than spans of their own: with ``batch="run"`` there
             # is one call per band run, too many to trace.
-            sp.count(verify_ns=round(stats.verify_seconds * 1e9))
+            sp.count(verify_ns=round(stats.verify_seconds * 1e9),
+                     groups=groups, recompressed=recompressed)
         self.stats.add(stats)
         return stats
+
+    def _feed_loop(self, source, stats, flush) -> tuple[int, int]:
+        """The scalar merge: one run at a time, in Python.  Serves
+        ``batch="run"`` (a flush after every run) and a merge without
+        disjoint sets.  Returns (runs of >= 2 members, 0)."""
+        uf = self.uf
+        evaluated = self.evaluated
+        pending: list[tuple[int, int]] = []
+        pending_set: set[tuple[int, int]] = set()
+        groups = 0
+
+        def flush_pending():
+            flush(np.array(pending, dtype=np.int64).reshape(-1, 2))
+            pending.clear()
+            pending_set.clear()
+
+        for band_runs in source.iter_bands():
+            for members in band_runs.iter_groups():
+                groups += 1
+                m = len(members)
+                stats.pairs_generated += m * (m - 1) // 2
+                if self.use_disjoint_sets:
+                    # "replace D with D.find()" — compress to roots.
+                    uniq = np.unique([uf.find(int(d)) for d in members])
+                else:
+                    uniq = np.sort(members)
+                k = len(uniq)
+                if k < 2:
+                    # All members already co-clustered: all excluded.
+                    stats.pairs_excluded += m * (m - 1) // 2
+                    continue
+                # Pairs collapsed by prior clustering are excluded too.
+                stats.pairs_excluded += (m * (m - 1) // 2
+                                         - k * (k - 1) // 2)
+                for ii in range(k):
+                    for jj in range(ii + 1, k):
+                        key = (int(uniq[ii]), int(uniq[jj]))
+                        if key in evaluated or key in pending_set:
+                            stats.pairs_excluded += 1
+                            continue
+                        pending.append(key)
+                        pending_set.add(key)
+                if self.batch == "run" or \
+                        len(pending) >= self.max_batch_pairs:
+                    flush_pending()
+            if self.batch == "band":
+                flush_pending()
+        flush_pending()
+        return groups, 0
+
+    def _feed_arrays(self, source, stats, flush) -> tuple[int, int]:
+        """``_feed_loop`` at band granularity with disjoint sets, in
+        array passes: the same pending blocks, flushes, unions and
+        counts, bit for bit.
+
+        Between two flushes the roots and the evaluated set are fixed,
+        so a stretch of runs compresses in one pass: the roots of every
+        member (``find_many``), each run's sorted unique roots, their
+        pairs in the loop's row-major order, and which pairs are new
+        (neither evaluated nor pending, nor met earlier in the stretch).
+        The band is cut where the loop flushes: after the first run at
+        which the pending pairs reach ``max_batch_pairs``.  A flush that unions changes roots, so the
+        runs after its cut are compressed again.  A stretch holds about
+        twice the runs that could fill the block, so that work stays
+        bounded.  Returns (runs of >= 2 members, runs compressed again).
+        """
+        uf = self.uf
+        cap = self.max_batch_pairs
+        # A pair evaluated or pending is in ``old`` (the sorted list) or
+        # in ``recent`` (evaluated since it was sorted, or pending or
+        # evaluated in this feed): folding ``recent`` into the sorted
+        # list would re-sort every pair on each feed.
+        old = self._sorted.keys
+        recent = np.sort(_sort_keys(self._unsorted()))
+        pending: list[np.ndarray] = []
+        n_pending = groups = recompressed = 0
+
+        for band_runs in source.iter_bands():
+            starts, ends = band_runs.run_starts, band_runs.run_ends
+            multi = ends - starts >= 2
+            starts, ends = starts[multi], ends[multi]
+            groups += len(starts)
+            m = ends - starts
+            # Pairs the runs before each run generate (the run's, too,
+            # at the end): an upper bound on the new pairs.
+            n_pairs = np.concatenate([[0], np.cumsum(m * (m - 1) // 2)])
+            g = 0
+            while g < len(starts):
+                # The stretch [g, w): enough runs to fill the block twice
+                # over if no pair were excluded.
+                w = int(np.searchsorted(
+                    n_pairs, n_pairs[g] + 2 * (cap - n_pending)))
+                w = min(max(w, g + 1), len(starts))
+                keys, run = _run_root_pairs(
+                    uf, band_runs.sorted_docs, starts[g:w], ends[g:w])
+                new = ~(_in_sorted(old, keys) | _in_sorted(recent, keys))
+                first = np.zeros(len(keys), bool)
+                first[np.unique(keys, return_index=True)[1]] = True
+                new &= first
+                n_new = np.concatenate([[0], np.cumsum(
+                    np.bincount(run[new], minlength=w - g))])
+                # Commit runs [c, e) a block at a time: e - 1 is the run
+                # at which the pending pairs reach ``cap``, or the last.
+                c = 0
+                while c < w - g:
+                    e = int(np.searchsorted(n_new,
+                                            n_new[c] + cap - n_pending))
+                    full = e <= w - g
+                    e = min(e, w - g)
+                    lo, hi = np.searchsorted(run, [c, e])
+                    took = keys[lo:hi][new[lo:hi]]
+                    generated = int(n_pairs[g + e] - n_pairs[g + c])
+                    stats.pairs_generated += generated
+                    stats.pairs_excluded += generated - len(took)
+                    pending.append(took)
+                    n_pending += len(took)
+                    recent = _merge_sorted(recent, np.sort(took))
+                    c = e
+                    if full:
+                        unioned = flush(_pairs_of(pending))
+                        pending, n_pending = [], 0
+                        if unioned:
+                            recompressed += w - g - c
+                            break
+                g += c
+            flush(_pairs_of(pending))
+            pending, n_pending = [], 0
+        return groups, recompressed
 
 
 def cluster_source(

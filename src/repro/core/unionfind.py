@@ -73,6 +73,20 @@ class ThresholdUnionFind:
             self.parent[x], x = root, self.parent[x]
         return int(root)
 
+    def find_many(self, xs: np.ndarray) -> np.ndarray:
+        """Roots of ``xs`` (int64 array) in one array pass: every lane
+        steps to its parent until no lane moves; then ``xs`` point
+        straight at their roots.  Roots are ``find``'s, lane for lane."""
+        parent = self.parent
+        roots = parent[xs]
+        while True:
+            up = parent[roots]
+            if np.array_equal(up, roots):
+                break
+            roots = up
+        parent[xs] = roots
+        return roots
+
     def union(self, x: int, y: int, sim: float) -> bool:
         """Union by rank, guarded by the lower-bound threshold property.
 

@@ -1,8 +1,12 @@
 """Staged dedup engine: candidate sources agree, batched verifiers match
 the per-pair numpy oracle, and the engine reproduces the scalar loop."""
-import numpy as np
+import dataclasses
+import functools
 
-from repro.core import jaccard, lsh, shingle
+import numpy as np
+import pytest
+
+from repro.core import DedupSession, jaccard, lsh, shingle
 from repro.core.bandstore import Design1Store, Design2Store
 from repro.core.candidates import (
     BandMatrixSource, EdgeStreamSource, ShardedEdgeSource, StoreBandSource,
@@ -16,7 +20,7 @@ from repro.core.pipeline import DedupConfig, DedupPipeline, DedupResult
 from repro.core.streaming import StreamingDedup
 from repro.core.unionfind import ThresholdUnionFind
 from repro.core.verify import (
-    CallbackVerifier, DeviceScoredEdgeVerifier, ExactJaccardVerifier,
+    BatchVerifier, CallbackVerifier, DeviceScoredEdgeVerifier, ExactJaccardVerifier,
     ShardedEdgeVerifier, SignatureVerifier,
 )
 from repro.data import inject_near_duplicates, make_i2b2_like
@@ -594,3 +598,161 @@ def test_num_clusters_counts_only_multidoc_clusters():
         bands=np.zeros((7, 1, 2), np.uint32))
     assert res.num_clusters == 2
     assert res.num_duplicates_removed == 3
+
+
+# -- the array merge at band granularity against the scalar loop -----------
+
+class _TableVerifier(BatchVerifier):
+    """``sims[a, b]`` from a fixed table, whatever the batches."""
+
+    def __init__(self, sims):
+        super().__init__()
+        self.sims = sims
+
+    def _verify_batch(self, pairs):
+        return self.sims[pairs[:, 0], pairs[:, 1]]
+
+
+def _sim_table(rng, n, lo=0.3):
+    s = rng.uniform(lo, 1.0, size=(n, n)).astype(np.float32)
+    return np.triu(s) + np.triu(s, 1).T
+
+
+def _runs_source(rng, n, num_bands=6):
+    """Each band splits the docs into runs of 1-9 members."""
+    bands = np.zeros((n, num_bands, 2), np.uint32)
+    for j in range(num_bands):
+        sizes = rng.randint(1, 10, size=n)
+        group = np.repeat(np.arange(n), sizes)[:n]
+        bands[rng.permutation(n), j, 0] = group
+    return BandMatrixSource(bands)
+
+
+def _edges_source(rng, n, num_edges=300):
+    """Random edges, each repeated, reversed or both, in two shards."""
+    e = rng.randint(0, n, size=(num_edges, 2))
+    e = np.concatenate([e, e[: num_edges // 3], e[::4, ::-1]])
+    return ShardedEdgeSource(e[rng.permutation(len(e))], num_docs=n,
+                             num_shards=2)
+
+
+def _band_merge(feeds, n, sims, *, cap, tree, rounds, edge, scalar):
+    verifier = _TableVerifier(sims)
+    acc = ClusterAccumulator(n, verifier, edge, tree, batch="band",
+                             max_batch_pairs=cap)
+    if scalar:
+        acc._feed_arrays = acc._feed_loop
+    stats = []
+    for i, source in enumerate(feeds):
+        st = acc.feed(source)
+        stats.append(dataclasses.replace(st, verify_seconds=0.0))
+        if rounds and i == 0:
+            merge_cluster_rounds(acc.uf, verifier, edge,
+                                 sim_cache=acc.evaluated)
+    return dict(labels=acc.uf.components().tolist(), pairs=acc.pairs,
+                stats=stats, batches=verifier.n_batches,
+                evaluated=list(acc.evaluated.items()),
+                min_score=acc.uf.min_score.tolist())
+
+
+BAND_CASES = {
+    **{f"{kind}-cap{cap}": (kind, cap, 0.40, False, 0.75)
+       for kind in ("runs", "edges") for cap in (1, 3, 8192)},
+    "tree-rejects": ("runs", 3, 0.65, False, 0.75),
+    "two-feeds-rounds": ("both", 3, 0.40, True, 0.75),
+    # float32(0.8) lies above 0.8: sims on the threshold are edges
+    # only when compared in float64, as the scalar loop compares them.
+    "sims-at-threshold": ("runs", 3, 0.40, False, 0.8),
+}
+
+
+@pytest.mark.parametrize("case", list(BAND_CASES))
+def test_band_arrays_match_scalar_loop(case):
+    """At ``batch="band"`` the array merge gives the scalar loop's
+    labels, pairs and sims, counts, verify batches and sim-cache order."""
+    kind, cap, tree, rounds, edge = BAND_CASES[case]
+    rng = np.random.RandomState(sorted(BAND_CASES).index(case))
+    n = 48
+    sims = _sim_table(rng, n)
+    if edge == 0.8:
+        sims = np.round(sims * 5).astype(np.float32) / np.float32(5)
+    feeds = {"runs": [_runs_source(rng, n)],
+             "edges": [_edges_source(rng, n)],
+             "both": [_runs_source(rng, n), _edges_source(rng, n)]}[kind]
+    run = functools.partial(_band_merge, feeds, n, sims, cap=cap,
+                            tree=tree, rounds=rounds, edge=edge)
+    ref, got = run(scalar=True), run(scalar=False)
+    assert sum(st.unions_done for st in ref["stats"]) > 0
+    if tree > 0.5:
+        assert sum(st.unions_rejected for st in ref["stats"]) > 0
+    if cap < 8192:   # cuts inside a band, with unions between them
+        assert ref["batches"] > sum(s.num_bands for s in feeds)
+    assert got == ref
+    if not rounds:   # edges by a float64 compare, as ``float(sim) >``
+        sim = np.array([v for _, v in got["evaluated"]])
+        assert sum(st.pairs_above_edge for st in got["stats"]) == \
+            np.count_nonzero(sim > edge)
+
+
+@pytest.mark.parametrize("cap", [8192, 16])
+def test_band_arrays_match_scalar_loop_in_session(cap, monkeypatch):
+    """The paper's settings in a session over templated notes: the
+    within-chunk bands and the cross-step edges through both merges."""
+    notes, _ = inject_near_duplicates(make_i2b2_like(72, seed=11), 48,
+                                      seed=12)
+    cfg = DedupConfig(ngram=8, num_hashes=100, rows_per_band=2,
+                      edge_threshold=0.75, tree_threshold=0.40,
+                      use_disjoint_sets=True, exact_verification=True,
+                      verify_batch="band")
+
+    def ingest():
+        sess = DedupSession(cfg, backend="host")
+        sess.acc.max_batch_pairs = cap
+        for i in range(0, len(notes), 40):
+            snap = sess.ingest(notes[i:i + 40])
+        return (snap.labels.tolist(), snap.pairs,
+                dataclasses.replace(snap.stats, verify_seconds=0.0))
+
+    got = ingest()
+    with monkeypatch.context() as m:
+        m.setattr(ClusterAccumulator, "_feed_arrays",
+                  ClusterAccumulator._feed_loop)
+        ref = ingest()
+    assert ref[2].unions_done > 0 and ref[2].pairs_evaluated > 0
+    assert got == ref
+
+
+def _merge_span_counts(tmp_path, source, sims, cap):
+    import jax
+
+    from repro.core import spans
+
+    acc = ClusterAccumulator(source.num_docs, _TableVerifier(sims), 0.75,
+                             0.0, batch="band", max_batch_pairs=cap)
+    spans.take()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        acc.feed(source)
+    finally:
+        jax.profiler.stop_trace()
+    (counts,) = [c for name, *_, c in spans.take() if name == "dedup.merge"]
+    return counts
+
+
+def test_merge_span_counts_groups_and_recompressed(tmp_path):
+    """``groups`` counts the runs of two or more members; ``recompressed``
+    the runs compressed again after a flush that unioned."""
+    rng = np.random.RandomState(3)
+    source = _runs_source(rng, 40)
+    n_groups = sum(1 for b in source.iter_bands() for _ in b.iter_groups())
+    low = np.full((40, 40), 0.5, np.float32)     # no pair is an edge
+    counts = _merge_span_counts(tmp_path / "low", source, low, 1)
+    assert counts["groups"] == n_groups and counts["recompressed"] == 0
+
+    # One band of three edges, a flush after each: the first unions 0
+    # and 1, so the two runs after it are compressed again.
+    chain3 = ShardedEdgeSource(np.array([[0, 1], [1, 2], [0, 2]]),
+                               num_docs=3)
+    counts = _merge_span_counts(tmp_path / "high", chain3,
+                                np.full((3, 3), 0.9, np.float32), 1)
+    assert counts["groups"] == 3 and counts["recompressed"] > 0
