@@ -1,33 +1,13 @@
-"""Roofline tables (EXPERIMENTS.md §Roofline).
+"""Measured dedup-ingest roofline (``run_ingest_roofline``).
 
-Two parts:
-
-* the ANALYTIC table from dry-run artifacts (experiments/dryrun/*.json,
-  produced by repro.launch.dryrun) — does NOT recompile anything, and
-* the MEASURED dedup-ingest roofline (``run_ingest_roofline``) — times
-  the staged three-dispatch ingest chain against the fused one-pass
-  kernel on this host's devices and reports docs/sec/device alongside
-  the analytic HBM bytes each path moves.  Artifact-independent, so it
-  runs even when no dry-run artifacts exist.
+Times the staged three-dispatch ingest chain against the fused one-pass
+kernel on this host's devices and reports docs/sec/device alongside the
+analytic HBM bytes each path moves, then the host->device transfer of
+padded tokens against raw bytes (``run_transfer_roofline``).
 """
 from __future__ import annotations
 
-import glob
-import json
-import os
-
 from benchmarks.common import emit, section, timeit
-
-ART_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "experiments", "dryrun")
-
-
-def load_records(art_dir: str = ART_DIR):
-    recs = []
-    for path in sorted(glob.glob(os.path.join(art_dir, "*.json"))):
-        with open(path) as f:
-            recs.append(json.load(f))
-    return recs
 
 
 def ingest_bytes_moved(D: int, L: int, M: int, r: int,
@@ -195,31 +175,5 @@ def run_transfer_roofline(D: int = 256, M: int = 128,
         f"backend={jax.default_backend()};D={D};M={M}")
 
 
-def run(art_dir: str = ART_DIR):
-    section("roofline terms per (arch x cell x mesh)")
-    recs = load_records(art_dir)
-    if not recs:
-        emit("roofline_no_artifacts", 0.0,
-             "run `python -m repro.launch.dryrun` first")
-    for r in recs:
-        tag = f"{r['arch']}__{r['cell']}__{r['mesh']}"
-        if r["status"] != "ok":
-            emit(f"roofline_{tag}", 0.0, r["status"])
-            continue
-        roof = r["roofline"]
-        emit(
-            f"roofline_{tag}", roof["step_s"] * 1e6,
-            f"compute={roof['compute_s']:.3g}s;"
-            f"memory={roof['memory_s']:.3g}s;"
-            f"collective={roof['collective_s']:.3g}s;"
-            f"bottleneck={roof['bottleneck']};"
-            f"frac={roof['roofline_fraction']:.4f};"
-            f"flops_eff={roof['flops_efficiency']:.3f}")
-    # The measured ingest roofline is artifact-independent: report it on
-    # BOTH paths (previously the no-artifact path emitted only the
-    # placeholder row and no roofline at all).
-    run_ingest_roofline()
-
-
 if __name__ == "__main__":
-    run()
+    run_ingest_roofline()

@@ -21,7 +21,9 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: accuracy,designs,"
-                         "clustering,scale,kernels,roofline,serving")
+                         "clustering,scale,kernels,serving,roofline "
+                         "(roofline: the measured ingest roofline, "
+                         "staged vs fused and tokens vs bytes)")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny-size CI smoke: sharded-vs-host parity, "
                          "verify throughput, band-group merge overlap; "
@@ -118,7 +120,7 @@ def main(argv=None) -> None:
         kernels.run()
     if want("roofline"):
         from benchmarks import roofline
-        roofline.run()
+        roofline.run_ingest_roofline()
     if want("serving"):
         from benchmarks import serving_dedup
         serving_dedup.run()

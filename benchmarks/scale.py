@@ -38,8 +38,6 @@ def run():
     hours_10m = 10e6 / rate / 3600
     emit("scale_extrapolate_10M_hours", 0.0,
          f"{hours_10m:.2f}h_single_CPU(paper:75h_signatures)")
-    # On the 256-chip pod the dedup step is embarrassingly parallel over
-    # docs; the dry-run artifact gives the per-step roofline instead.
 
     section("§12-style cluster stats (4k-note corpus w/ heavy duplication)")
     notes = make_i2b2_like(1500, seed=5)

@@ -22,8 +22,8 @@ but are not API-stable.  The blessed names:
 * read path — ``SessionView`` (``DedupSession.view()``),
   ``QueryResult`` / ``query_view`` (``core.query``), and the serving
   shell ``DedupQueryService`` (``serving.dedup_service``, re-exported
-  here lazily so importing ``repro.core`` never pulls the serving
-  stack).
+  here lazily: ``dedup_service`` imports this package, so a plain
+  import would be a cycle).
 
 Naming scheme for ingest-shaped entry points: a method is named
 ``ingest*`` iff it ADDS DOCUMENTS to long-lived dedup state —
@@ -151,9 +151,11 @@ _sanitize.maybe_install()
 
 
 def __getattr__(name: str):
-    # Lazy re-export: the serving shell lives in repro.serving (its
-    # package pulls the model stack), so it is resolved on first
-    # access instead of at `import repro.core` time.
+    # Lazy re-export: repro.serving.dedup_service imports repro.core
+    # submodules, so a plain import here would be a cycle. Importing
+    # repro.serving first would run this package's __init__ while
+    # dedup_service is half-initialised and fail to find
+    # DedupQueryService; resolving it on first access breaks the cycle.
     if name == "DedupQueryService":
         from repro.serving.dedup_service import DedupQueryService
 

@@ -470,15 +470,6 @@ def make_dedup_step(cfg: DistLSHConfig, mesh: Mesh):
     return dedup_step
 
 
-def dedup_input_specs(cfg: DistLSHConfig, num_docs: int, max_len: int):
-    """ShapeDtypeStructs for the dry-run (no allocation)."""
-    return {
-        "tokens": jax.ShapeDtypeStruct((num_docs, max_len), jnp.uint32),
-        "lengths": jax.ShapeDtypeStruct((num_docs,), jnp.int32),
-        "seeds": jax.ShapeDtypeStruct((cfg.num_hashes,), jnp.uint32),
-    }
-
-
 # ---------------------------------------------------------------------------
 # Host-side merge: stage-2 verify + clustering through the shared engine
 # ---------------------------------------------------------------------------

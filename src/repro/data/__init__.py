@@ -2,12 +2,8 @@ from repro.data.corpus import (
     accuracy_testset, clustering_testset, inject_near_duplicates,
     make_i2b2_like, perturb,
 )
-from repro.data.loader import (
-    CleanDataset, build_clean_dataset, hash_tokenize, synthetic_batch_fn,
-)
 
 __all__ = [
     "make_i2b2_like", "perturb", "inject_near_duplicates",
-    "accuracy_testset", "clustering_testset", "CleanDataset",
-    "build_clean_dataset", "hash_tokenize", "synthetic_batch_fn",
+    "accuracy_testset", "clustering_testset",
 ]

@@ -18,7 +18,6 @@ from repro.kernels.sigjaccard import (
     masked_pair_counts,
     pair_counts,
 )
-from repro.kernels.flash_attention import flash_attention
 
 __all__ = [
     "minhash_signatures",
@@ -32,5 +31,4 @@ __all__ = [
     "masked_indexed_pair_counts",
     "masked_indexed_pair_estimate",
     "masked_pair_counts",
-    "flash_attention",
 ]

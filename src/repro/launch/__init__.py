@@ -1,8 +1,4 @@
-"""Launchers: mesh builders, multi-pod dry-run, train/serve/dedup drivers.
-
-NOTE: do not import ``repro.launch.dryrun`` from library code — importing
-it sets XLA_FLAGS for 512 host devices (it is a __main__ entry point).
+"""Command-line entry points: the dedup driver (``python -m
+repro.launch.dedup``) and the compile-cache setup it shares with
+``chip_smoke.py``.
 """
-from repro.launch.mesh import make_production_mesh, make_test_mesh
-
-__all__ = ["make_production_mesh", "make_test_mesh"]

@@ -1,31 +1,8 @@
-"""Serving shells: LM continuous batching + the dedup query service.
+"""Serving shell: the dedup query service over a warm ``DedupSession``."""
+from repro.serving.dedup_service import (
+    DedupQueryService,
+    QueryRequest,
+    QueryServiceStats,
+)
 
-Submodules are imported lazily: ``engine`` pulls the model stack
-(``repro.models``), which the dedup query service does not need — so
-``from repro.serving import DedupQueryService`` stays light.
-"""
-
-__all__ = [
-    "ServeEngine",
-    "Request",
-    "EngineStats",
-    "DedupQueryService",
-    "QueryRequest",
-    "QueryServiceStats",
-]
-
-_ENGINE = ("ServeEngine", "Request", "EngineStats")
-_DEDUP = ("DedupQueryService", "QueryRequest", "QueryServiceStats")
-
-
-def __getattr__(name: str):
-    if name in _ENGINE:
-        from repro.serving import engine
-
-        return getattr(engine, name)
-    if name in _DEDUP:
-        from repro.serving import dedup_service
-
-        return getattr(dedup_service, name)
-    raise AttributeError(
-        f"module {__name__!r} has no attribute {name!r}")
+__all__ = ["DedupQueryService", "QueryRequest", "QueryServiceStats"]

@@ -15,12 +15,11 @@ Two calling styles:
 
 * **Synchronous** — ``query(texts)`` runs one batch end to end.
 * **Microbatched** — ``submit`` / ``step`` / ``run_until_drained``,
-  the same slot/queue shape as ``serving.engine.ServeEngine``'s
   continuous batching: callers enqueue single documents, each ``step``
   drains up to ``max_batch`` of them and executes ONE fused-ingest +
-  probe + ONE batched device verify for the whole microbatch.  Per-
-  query work is dominated by fixed dispatch overheads, so batching N
-  queries costs far less than N sequential calls — that is the QPS
+  probe + ONE batched device verify for the whole microbatch.
+  Per-query work is dominated by fixed dispatch overheads, so batching
+  N queries costs far less than N sequential calls — that is the QPS
   story ``benchmarks/serving_dedup.py`` measures — while results are
   bit-identical to sequential queries (pinned by
   ``tests/test_query_service.py``).

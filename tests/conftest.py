@@ -1,14 +1,13 @@
 """Shared test utilities.
 
-NOTE: XLA_FLAGS / device-count forcing is NOT set here (the dry-run owns
-that); multi-device tests spawn subprocesses via ``run_with_devices``.
+NOTE: XLA_FLAGS / device-count forcing is NOT set here: it must precede
+the first jax import, so multi-device tests spawn subprocesses via
+``run_with_devices``.
 """
 import os
 import subprocess
 import sys
 import textwrap
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
@@ -28,8 +27,3 @@ def run_with_devices(code: str, n_devices: int, timeout: int = 600):
             f"subprocess failed:\nSTDOUT:\n{proc.stdout}\n"
             f"STDERR:\n{proc.stderr[-4000:]}")
     return proc.stdout
-
-
-@pytest.fixture
-def tmp_ckpt_dir(tmp_path):
-    return str(tmp_path / "ckpt")

@@ -1,11 +1,5 @@
-"""Data pipeline: corpus generation, dedup-integrated loader."""
-import numpy as np
-
-from repro.core.pipeline import DedupConfig
-from repro.data import (
-    build_clean_dataset, hash_tokenize, inject_near_duplicates,
-    make_i2b2_like, synthetic_batch_fn,
-)
+"""Data pipeline: corpus generation."""
+from repro.data import inject_near_duplicates, make_i2b2_like
 
 
 def test_corpus_shape():
@@ -24,31 +18,3 @@ def test_injection_provenance():
         a, b = out[dup_idx].split(), out[src_idx].split()
         same = sum(x == y for x, y in zip(a, b)) / max(len(a), 1)
         assert same >= 1 - frac - 0.02
-
-
-def test_hash_tokenizer_stable_and_bounded():
-    ids = hash_tokenize("the patient denies chest pain", 1000)
-    ids2 = hash_tokenize("the patient denies chest pain", 1000)
-    assert np.array_equal(ids, ids2)
-    assert ids.min() >= 2 and ids.max() < 1000
-
-
-def test_clean_dataset_removes_duplicates_and_batches():
-    notes = make_i2b2_like(60, seed=3)
-    notes = notes + [notes[0]] * 5
-    ds = build_clean_dataset(notes, vocab_size=512,
-                             dedup_cfg=DedupConfig())
-    assert ds.num_docs_in == 65
-    assert ds.num_docs_kept <= 60
-    b1 = ds.batch_at(3, batch=2, seq=32)
-    b2 = ds.batch_at(3, batch=2, seq=32)
-    assert np.array_equal(b1["tokens"], b2["tokens"])   # pure in step
-    assert b1["tokens"].shape == (2, 32)
-    assert not np.array_equal(b1["tokens"],
-                              ds.batch_at(4, 2, 32)["tokens"])
-
-
-def test_synthetic_batch_fn_deterministic():
-    fn = synthetic_batch_fn(100, 2, 8, seed=5)
-    assert np.array_equal(fn(7)["tokens"], fn(7)["tokens"])
-    assert not np.array_equal(fn(7)["tokens"], fn(8)["tokens"])

@@ -1,5 +1,5 @@
 """Multi-device integration (subprocesses with forced host devices):
-distributed LSH, EP MoE, sharded train step, dry-run smoke."""
+the distributed LSH path against the one-device host path."""
 import pytest
 
 from tests.conftest import run_with_devices
@@ -592,113 +592,3 @@ def test_dist_lsh_overflow_retry_through_engine():
         assert res_no.stats.unions_done <= res.stats.unions_done
         print("overflow retry ok")
     """, n_devices=8)
-
-
-@pytest.mark.slow
-def test_ep_moe_matches_global():
-    run_with_devices("""
-        import numpy as np, jax, jax.numpy as jnp
-        from repro.models.config import ModelConfig, MoECfg
-        from repro.models.layers import Builder
-        from repro.models.moe import make_moe, moe_ffn
-        from repro.models.moe_sharded import moe_ffn_ep
-        from repro.models import sharding as shlib
-        cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=32,
-                          n_heads=4, n_kv_heads=2, d_ff=64,
-                          vocab_size=128,
-                          moe=MoECfg(n_experts=8, top_k=2, n_shared=1,
-                                     d_expert=48, capacity_factor=8.0),
-                          param_dtype="float32",
-                          compute_dtype="float32")
-        b = Builder(jax.random.PRNGKey(0), jnp.float32)
-        make_moe(b, cfg); p = b.params["moe"]
-        x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
-        ref, _ = moe_ffn(p, cfg, x)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
-        with shlib.activate(mesh):
-            out, _ = jax.jit(lambda p_, x_: moe_ffn_ep(p_, cfg, x_))(p, x)
-            g1 = jax.jit(jax.grad(
-                lambda p_: jnp.sum(moe_ffn_ep(p_, cfg, x)[0]**2)))(p)
-        g0 = jax.grad(lambda p_: jnp.sum(moe_ffn(p_, cfg, x)[0]**2))(p)
-        assert np.abs(np.asarray(out) - np.asarray(ref)).max() < 1e-4
-        for k in g0:
-            assert np.abs(np.asarray(g1[k]) - np.asarray(g0[k])).max() \
-                < 1e-3, k
-        print("ep moe ok")
-    """, n_devices=4)
-
-
-@pytest.mark.slow
-def test_sharded_train_step_runs_and_matches_single_device():
-    run_with_devices("""
-        import numpy as np, jax, jax.numpy as jnp
-        from repro import optim
-        from repro.configs import get_reduced
-        from repro.launch.mesh import make_test_mesh
-        from repro.models.sharding import activate
-        from repro.training.step import (TrainConfig, init_state,
-                                         make_train_step,
-                                         shard_train_step)
-        cfg = get_reduced("olmo-1b")
-        tcfg = TrainConfig(adamw=optim.AdamWConfig(lr=1e-3),
-                           warmup_steps=1)
-        state, axes = init_state(cfg, tcfg, jax.random.PRNGKey(0))
-        batch = {"tokens": np.random.RandomState(0).randint(
-            0, cfg.vocab_size, (4, 16)).astype(np.int32)}
-        ref_state, ref_m = jax.jit(make_train_step(cfg, tcfg))(
-            jax.tree.map(jnp.copy, state), dict(batch))
-        mesh = make_test_mesh((2, 2), ("data", "model"))
-        with activate(mesh):
-            fn = shard_train_step(cfg, tcfg, mesh, axes, batch,
-                                  donate=False)
-            new_state, m = fn(state, batch)
-        assert abs(float(m["loss"]) - float(ref_m["loss"])) < 1e-4
-        d = jax.tree.map(
-            lambda a, b: float(jnp.max(jnp.abs(
-                a.astype(jnp.float32) - b.astype(jnp.float32)))),
-            new_state["params"], ref_state["params"])
-        assert max(jax.tree.leaves(d)) < 1e-4
-        print("sharded train ok")
-    """, n_devices=4)
-
-
-@pytest.mark.slow
-def test_dryrun_reduced_all_cells_small_mesh():
-    run_with_devices("""
-        from repro.launch import dryrun
-        for arch in ("olmo-1b", "deepseek-v2-236b", "mamba2-780m",
-                     "zamba2-2.7b", "whisper-medium", "h2o-danube-1.8b"):
-            for cell in ("train_4k", "prefill_32k", "decode_32k",
-                         "long_500k"):
-                rec = dryrun.run_cell(
-                    arch, cell, multi_pod=False, reduced=True,
-                    mesh_override=__import__(
-                        "repro.launch.mesh",
-                        fromlist=["make_test_mesh"]).make_test_mesh(
-                            (2, 2), ("data", "model")))
-                assert rec["status"] in ("ok",) or \
-                    rec["status"].startswith("skip"), rec
-        print("dryrun smoke ok")
-    """, n_devices=4, timeout=1200)
-
-
-def test_hlo_parse_trip_counts():
-    import jax
-    import jax.numpy as jnp
-
-    from repro.launch.hlo_parse import analyze
-
-    d = 128
-    ws = jnp.zeros((10, d, d))
-    x = jnp.zeros((d, d))
-
-    def body(x, w):
-        return x @ w, None
-
-    def scanned(x, ws):
-        y, _ = jax.lax.scan(body, x, ws)
-        return y
-
-    c = jax.jit(scanned).lower(x, ws).compile()
-    st = analyze(c.as_text())
-    assert abs(st.flops - 2 * 10 * d**3) / (2 * 10 * d**3) < 1e-6

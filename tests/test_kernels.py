@@ -149,47 +149,3 @@ def test_fused_ingest_sweep(d, l, n, r):
     seeds = rng.randint(0, 2**32, size=(m,), dtype=np.uint64
                         ).astype(np.uint32)
     assert_fused_parity(tokens, lengths, seeds, n=n, r=r)
-
-
-def test_flash_attention_vs_blockwise():
-    import jax
-    from repro.kernels.flash_attention import flash_attention
-    from repro.models.attention import blockwise_attention
-
-    rng = jax.random.PRNGKey(0)
-    for B, Sq, H, Hkv, Dh, window in [
-        (2, 64, 8, 2, 16, None),
-        (1, 100, 4, 4, 8, None),
-        (2, 96, 8, 2, 16, 24),
-        (1, 37, 6, 2, 16, None),
-    ]:
-        q = jax.random.normal(rng, (B, Sq, H, Dh), jnp.float32)
-        k = jax.random.normal(jax.random.fold_in(rng, 1),
-                              (B, Sq, Hkv, Dh), jnp.float32)
-        v = jax.random.normal(jax.random.fold_in(rng, 2),
-                              (B, Sq, Hkv, Dh), jnp.float32)
-        got = flash_attention(q, k, v, causal=True, window=window,
-                              tq=32, tk=32)
-        ref = blockwise_attention(q, k, v, causal=True, window=window,
-                                  block_kv=16)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=3e-5)
-
-
-def test_flash_attention_model_integration():
-    import jax
-    from repro.models import lm
-    from repro.models.config import ModelConfig
-
-    cfg = ModelConfig(name="flash_t", family="dense", n_layers=2,
-                      d_model=32, n_heads=4, n_kv_heads=2, d_ff=64,
-                      vocab_size=128, param_dtype="float32",
-                      compute_dtype="float32", remat="none",
-                      use_flash_attention=True)
-    params, _ = lm.init(cfg, jax.random.PRNGKey(0))
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1),
-                                          (2, 32), 0, 128)}
-    loss_f, _ = lm.loss_fn(cfg, params, batch)
-    loss_b, _ = lm.loss_fn(cfg.with_(use_flash_attention=False),
-                           params, batch)
-    assert abs(float(loss_f) - float(loss_b)) < 1e-4

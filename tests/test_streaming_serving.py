@@ -1,6 +1,4 @@
-"""Streaming dedup (paper §12 two-phase mode) + continuous-batching engine."""
-import numpy as np
-import jax
+"""Streaming dedup (paper §12 two-phase mode)."""
 
 from repro.core.pipeline import DedupConfig, DedupPipeline
 from repro.core.streaming import StreamingDedup, merge_cluster_rounds
@@ -59,50 +57,3 @@ def test_second_round_merging():
         edge_threshold=0.75)
     assert merges == 1
     assert uf.find(0) == uf.find(2)
-
-
-def test_serve_engine_continuous_batching():
-    from repro.configs import get_reduced
-    from repro.serving import ServeEngine
-    from repro.training.step import TrainConfig, init_state
-    from repro import optim
-
-    cfg = get_reduced("olmo-1b")
-    state, _ = init_state(cfg, TrainConfig(adamw=optim.AdamWConfig()),
-                          jax.random.PRNGKey(0))
-    eng = ServeEngine(cfg, state["params"], slots=4, cache_len=64,
-                      eos_id=-1)  # no eos in random model
-    rng = np.random.RandomState(0)
-    for _ in range(10):
-        eng.submit(rng.randint(2, cfg.vocab_size, size=rng.randint(4, 12)),
-                   max_tokens=6)
-    finished = eng.run_until_drained()
-    assert len(finished) == 10
-    assert all(len(r.out) == 6 for r in finished)
-    # continuous batching actually batched: 10 requests, 4 slots, 6 toks
-    # => at least ~60/4 = 15 decode steps, but far fewer than serial 60.
-    assert eng.stats.steps < 40
-    assert eng.stats.mean_occupancy > 0.5
-    assert eng.stats.tokens_out == 60
-
-
-def test_serve_engine_matches_offline_decode():
-    """Engine output == straight greedy decode for a single request."""
-    from repro.configs import get_reduced
-    from repro.launch.serve import serve_batch
-    from repro.serving import ServeEngine
-    from repro.training.step import TrainConfig, init_state
-    from repro import optim
-
-    cfg = get_reduced("phi3-medium-14b")
-    state, _ = init_state(cfg, TrainConfig(adamw=optim.AdamWConfig()),
-                          jax.random.PRNGKey(1))
-    prompt = np.random.RandomState(1).randint(2, cfg.vocab_size,
-                                              size=8).astype(np.int32)
-    toks_ref, _ = serve_batch(cfg, state["params"], prompt[None],
-                              max_new=5, cache_len=32)
-    eng = ServeEngine(cfg, state["params"], slots=2, cache_len=32,
-                      eos_id=-1)
-    eng.submit(prompt, max_tokens=5)
-    (req,) = eng.run_until_drained()
-    assert req.out == toks_ref[0].tolist(), (req.out, toks_ref[0])
