@@ -30,7 +30,10 @@ enforces this; ``bytes_to_bands`` also pads one extra column).
 Grid (D/TD, LB/TLB), L innermost.  VMEM per step is one (TLB, TD) uint8
 byte tile, its int32 copy, the uint32 token/end tiles and two (1, TD)
 carries.  The per-position token/end matrices go back to HBM, where
-XLA compacts them into the token matrix handed to ``fused_ingest``.
+``compact_token_ends`` packs each document's token ids, in order, into
+the dense token matrix handed to ``fused_ingest``: a stream compaction
+by log-step shifts, with no scatter, sort or gather (the chip compiler
+lowers a scatter over every byte position to a sort of all of them).
 """
 from __future__ import annotations
 
@@ -174,6 +177,46 @@ def byte_token_hashes(
     return tok.T[:D, :LB], ends.T[:D, :LB]
 
 
+def compact_token_ends(tok: jnp.ndarray, ends: jnp.ndarray,
+                       width: int) -> jnp.ndarray:
+    """(D, L) token ids + (D, L) token-end flags -> (D, width) uint32:
+    each row's ids at its end positions, in order, then zeros.
+
+    A stream compaction by log-step shifts, with no scatter, sort or
+    gather.  The k-th end of a row (rank k, from 0) at column p has to
+    move ``s = p - k`` columns left.  Round j moves every element whose
+    ``s`` has bit j set ``2**j`` columns left, bits from 0 upward; each
+    round is an elementwise select over a statically shifted copy.
+
+    No two elements ever meet: ``s`` does not decrease along a row, so
+    for ranks i < k at columns p_i < p_k, after rounds 0..j the gap is
+    ``(k - i) + 2**(j+1) * (s_k // 2**(j+1) - s_i // 2**(j+1)) >= k - i``.
+    So at most one element arrives at a column, and only where the one
+    there leaves or the column is empty.  A column with no token holds
+    value 0 and shift 0: it never moves, and an arrival overwrites it.
+    ``width`` must not exceed L (a row holds at most L ends).
+    """
+    D, L = tok.shape
+    assert width <= L, f"width {width} > {L} positions"
+    live = ends > 0
+    rank = jnp.cumsum(ends, axis=1, dtype=jnp.int32) - 1
+    shift = jnp.where(live, jnp.arange(L, dtype=jnp.int32) - rank, 0)
+    val = jnp.where(live, tok.astype(jnp.uint32), jnp.uint32(0))
+    for j in range((L - 1).bit_length()):
+        # Column c sees the element at c + 2**j; columns past the end
+        # see an empty one.  One pad with a negative low edge: XLA fuses
+        # it into the select, where a slice is first copied out whole.
+        left = [(0, 0, 0), (-(1 << j), 1 << j, 0)]
+        s_in = jax.lax.pad(shift, 0, left)
+        v_in = jax.lax.pad(val, jnp.uint32(0), left)
+        arrives = ((s_in >> j) & 1) == 1
+        stays = ((shift >> j) & 1) == 0
+        val = jnp.where(arrives, v_in,
+                        jnp.where(stays, val, jnp.uint32(0)))
+        shift = jnp.where(arrives, s_in, jnp.where(stays, shift, 0))
+    return val[:, :width]
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n", "r", "td", "tlb", "id_seed", "interpret"))
@@ -193,8 +236,9 @@ def bytes_to_bands(
     ((D, M) signatures, (D, M//r, 2) band values, (D,) token counts).
 
     The full zero-copy ingest: byte shingle kernel -> on-device token
-    compaction (cumsum/scatter; dropped positions go out of bounds) ->
-    ``fused_ingest``.  Bit-identical to host tokenize(do_stem=False) +
+    compaction (``compact_token_ends``: each token end moves left by
+    its position less its rank, in log-step rounds that never collide)
+    -> ``fused_ingest``.  Bit-identical to host tokenize(do_stem=False) +
     ``token_ids`` + ``pack_documents`` + ``fused_ingest``.  Callers feed
     pow2-bucketed widths (``pack_bytes`` + ``pow2_bucket``) so the
     compile set stays bounded — RPR003 audits call sites.
@@ -219,11 +263,7 @@ def bytes_to_bands(
     # hard cap; the width is derived from the bucketed LB, keeping the
     # downstream fused_ingest compile set bounded too.
     lt_bucket = (LB + 1) // 2 + 1
-    tidx = jnp.cumsum(ends, axis=1) - 1
-    dst = jnp.where(ends > 0, tidx, lt_bucket)  # non-ends dropped (OOB)
-    row = jnp.arange(D, dtype=jnp.int32)[:, None]
-    tokens = jnp.zeros((D, lt_bucket), jnp.uint32)
-    tokens = tokens.at[row, dst].set(tok, mode="drop")
+    tokens = compact_token_ends(tok, ends, lt_bucket)
     tok_lengths = jnp.sum(ends, axis=1).astype(jnp.int32)
 
     sig, bands, _ = fused_ingest(

@@ -117,6 +117,108 @@ def test_byte_kernel_tile_size_invariance():
             assert np.array_equal(g, w)
 
 
+# -- token compaction: log-step shifts against a plain numpy compaction -------
+
+def _compact_np(tok, ends, width):
+    """Each row's ids at its end positions, in order, zero-filled."""
+    out = np.zeros((tok.shape[0], width), np.uint32)
+    for d in range(tok.shape[0]):
+        ids = tok[d][ends[d] > 0]
+        out[d, :len(ids)] = ids
+    return out
+
+
+def _ends_rows(kinds, L, rng):
+    """(D, L) end flags, one row per kind: ``empty``, ``dense`` (an end
+    every second position from 1: the most a row can hold),
+    ``last_column`` (one end at L - 1), ``random`` (ends at least two
+    apart, as the tokenizer emits them)."""
+    ends = np.zeros((len(kinds), L), np.int32)
+    for d, kind in enumerate(kinds):
+        if kind == "dense":
+            ends[d, 1::2] = 1
+        elif kind == "last_column":
+            ends[d, L - 1] = 1
+        elif kind == "random":
+            last = -2
+            for p in np.flatnonzero(rng.rand(L) < rng.rand()):
+                if p >= 1 and p - last >= 2:
+                    ends[d, p], last = 1, p
+    return ends
+
+
+ALL_ROWS = ("empty", "dense", "last_column", "random", "random")
+
+
+def _compaction_case(case):
+    """(tok, ends) as (D, L) arrays for one named case, L = LB + 1."""
+    rng = np.random.RandomState(len(case))
+    if case == "clinical_notes":
+        from repro.data import inject_near_duplicates, make_i2b2_like
+
+        notes, _ = inject_near_duplicates(make_i2b2_like(12, seed=3), 4,
+                                          seed=4)
+        width = shingle.pow2_bucket(
+            max(len(t.encode("utf-8")) for t in notes) + 1)
+        packed = shingle.pack_bytes(notes, width)
+        data = np.pad(packed.data, ((0, 0), (0, 1)))
+        return shingle.byte_token_hashes_np(data, packed.lengths)
+    L, kinds = {
+        "width_2": (2, ALL_ROWS),
+        "width_33": (33, ALL_ROWS),
+        "width_257": (257, ALL_ROWS),
+        "width_2049": (2049, ALL_ROWS),
+        "empty_rows": (65, ("empty",) * 3),
+        "full_density": (65, ("dense",) * 3),
+        "end_at_last_column": (65, ("last_column", "dense", "random")),
+        "one_document": (129, ("random",)),
+        "docs_not_multiple_of_128": (129, ALL_ROWS * 26),
+    }[case]
+    ends = _ends_rows(kinds, L, rng)
+    # Ids everywhere: the compaction must keep only those at the ends.
+    tok = rng.randint(0, 2**32, size=ends.shape, dtype=np.uint64
+                      ).astype(np.uint32)
+    return tok, ends
+
+
+@pytest.mark.parametrize("case", [
+    "width_2", "width_33", "width_257", "width_2049", "empty_rows",
+    "full_density", "end_at_last_column", "one_document",
+    "docs_not_multiple_of_128", "clinical_notes"])
+def test_compact_token_ends_matches_numpy(case):
+    """``compact_token_ends`` == the plain compaction, bit for bit, at
+    ``bytes_to_bands``' width (LB + 1) // 2 + 1 for L = LB + 1."""
+    from repro.kernels.byte_shingle import compact_token_ends
+
+    tok, ends = _compaction_case(case)
+    D, L = tok.shape
+    width = L // 2 + 1
+    got = np.asarray(compact_token_ends(
+        jnp.asarray(tok), jnp.asarray(ends), width))
+    np.testing.assert_array_equal(got, _compact_np(tok, ends, width))
+    if case == "full_density":
+        # A token every second byte fills all but the last column.
+        assert (ends.sum(axis=1) == width - 1).all()
+    if case == "docs_not_multiple_of_128":
+        assert D % 128 != 0
+
+
+def test_compact_token_ends_has_no_scatter_or_sort():
+    """At the bulk cell's shape (8,192 notes of 2,048 bytes: 2,049
+    positions, 1,025 token columns) the compaction lowers to neither a
+    scatter nor a sort."""
+    import jax
+    from repro.kernels.byte_shingle import compact_token_ends
+
+    text = jax.jit(compact_token_ends, static_argnums=2).lower(
+        jax.ShapeDtypeStruct((8192, 2049), jnp.uint32),
+        jax.ShapeDtypeStruct((8192, 2049), jnp.int32),
+        1025).as_text()
+    assert "stablehlo.scatter" not in text
+    assert "stablehlo.sort" not in text
+    assert "stablehlo.gather" not in text
+
+
 # -- the fused bytes->bands chain --------------------------------------------
 
 def test_bytes_to_bands_matches_host_chain():

@@ -85,6 +85,24 @@ def test_bytes_to_bands_compiles(spec, n, m, r):
         spec((m,), jnp.uint32), count=2)
 
 
+def test_bytes_to_bands_at_bulk_cell_shape_has_no_sort_or_scatter(spec):
+    """The bulk admission cell's program (8,192 notes of 2,048 bytes at
+    FineWeb's widths) compacts its tokens with neither a sort nor a
+    scatter: the chip compiler turns a scatter over every byte position
+    into a sort of all of them."""
+    import re
+
+    from repro.kernels.byte_shingle import bytes_to_bands
+
+    text = jax.jit(
+        lambda d, ln, s: bytes_to_bands(d, ln, s, n=5, r=8,
+                                        interpret=False)
+    ).lower(spec((8192, 2048), jnp.uint8), spec((8192,), jnp.int32),
+            spec((112,), jnp.uint32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert not re.search(r"\b(sort|scatter)\(", text)
+
+
 @pytest.mark.parametrize("entry,m,store,pairs", [
     pytest.param("pair_counts", M, None, 4096, id="pair_counts"),
     pytest.param("indexed_pair_counts", M, (8192, M), 4096,
